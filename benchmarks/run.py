@@ -36,6 +36,10 @@ def main() -> None:
     args = ap.parse_args()
     wanted = tuple(args.only.split(",")) if args.only else SUITES
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     os.makedirs(args.out, exist_ok=True)
     print("name,us_per_call,derived")
     for name in wanted:

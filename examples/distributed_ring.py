@@ -1,27 +1,41 @@
 """Distributed ParaLiNGAM: the row-block ring find-root AND the ring-driven
-full causal order on an 8-device host mesh (the same shard_map code paths
-the 512-chip dry-run exercises).
+full causal order over the devices this host has.
 
     PYTHONPATH=src python examples/distributed_ring.py
+
+On a CPU-only machine, give it host devices to ring over:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        PYTHONPATH=src python examples/distributed_ring.py
+
+The mesh is built from ``jax.devices()``: the largest power-of-two count
+of them (all of 1, 2, 4 or 8 chips) as ring shards, with a ``model`` axis
+of 2 sharding the samples when there are 8 or more.
 """
-
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.covariance import cov_matrix, normalize
-from repro.core.paralingam import find_root_dense
+from repro.core.paralingam import ParaLiNGAMConfig, causal_order_scan, find_root_dense
 from repro.core.sem import SemSpec, generate
 from repro.dist.ring import ring_find_root_jit
+from repro.dist.ring_order import causal_order_ring
 
-mesh = jax.make_mesh((4, 2), ("data", "model"),
-                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+n_dev = 1 << (len(jax.devices()).bit_length() - 1)  # largest power of two
+model = 2 if n_dev >= 8 else 1
+ring = n_dev // model
+
+
+def make_ring_mesh(pods, ring, model):
+    return jax.make_mesh((pods, ring, model), ("pod", "ring", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3,
+                         devices=jax.devices()[:n_dev])
+
+
+mesh = make_ring_mesh(pods=1, ring=ring, model=model)
 print("mesh:", dict(zip(mesh.axis_names, mesh.devices.shape)))
 
 data = generate(SemSpec(p=64, n=4096, seed=1))
@@ -32,39 +46,33 @@ mask = jnp.ones((64,), bool)
 root_1, s_1 = find_root_dense(xn, c, mask, block_j=64)
 with jax.set_mesh(mesh):
     fn = ring_find_root_jit(mesh)
-    root_8, s_8 = fn(xn, c, mask)  # compile
+    root_r, s_r = fn(xn, c, mask)  # compile
     t0 = time.time()
     for _ in range(5):
-        root_8, s_8 = fn(xn, c, mask)
-    jax.block_until_ready(s_8)
+        root_r, s_r = fn(xn, c, mask)
+    jax.block_until_ready(s_r)
     dt = (time.time() - t0) / 5
 
-print(f"single-device root={int(root_1)}  ring root={int(root_8)}  "
-      f"scores match: {bool(jnp.allclose(s_1, s_8, rtol=2e-4))}")
-print(f"ring find-root: {dt * 1e3:.1f} ms / iteration on 8 host devices")
+print(f"single-device root={int(root_1)}  ring root={int(root_r)}  "
+      f"scores match: {bool(jnp.allclose(s_1, s_r, rtol=2e-4))}")
+print(f"ring find-root: {dt * 1e3:.1f} ms / iteration on {n_dev} "
+      f"{jax.devices()[0].platform} devices")
 
 # --- full causal order through the ring: all p iterations device-resident
-# on a ("ring", "model") mesh — 4 row-block shards x 2 sample shards with
-# psum'd entropy moments. Each device holds p/4 rows x n/2 samples.
-from repro.core.paralingam import ParaLiNGAMConfig, causal_order_scan
-from repro.dist.ring_order import causal_order_ring
-from jax.sharding import Mesh
-
-ring_mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("ring", "model"))
-# score_backend="pallas" would compute each shard's entropy moments with the
-# moments-emitting kernel; the raw sums feed the same cross-shard pmean.
+# on the ("pod", "ring", "model") mesh — row-block shards over "ring",
+# sample shards with psum'd entropy moments over "model".
 cfg = ParaLiNGAMConfig(order_backend="ring", min_bucket=8)
 res_scan = causal_order_scan(data["x"], ParaLiNGAMConfig(min_bucket=8))
-res_ring = causal_order_ring(data["x"], cfg, mesh=ring_mesh)
+res_ring = causal_order_ring(data["x"], cfg, mesh=mesh)
 print(f"ring order == single-shard scan order: {res_ring.order == res_scan.order}")
 print(f"first 8 of causal order: {res_ring.order[:8]}")
 
 # --- threshold inside the ring: the comparison-saving state machine (paper
 # Algorithms 4-6) runs per shard, with messaging credits and done-masks
-# riding the ring packet. Orders are bit-identical to the dense ring; the
-# device-measured counters show the saved work.
+# riding the ring packet. Orders match the dense ring; the device-measured
+# counters show the saved work.
 cfg_thr = ParaLiNGAMConfig(order_backend="ring", threshold=True, min_bucket=8)
-res_thr = causal_order_ring(data["x"], cfg_thr, mesh=ring_mesh)
+res_thr = causal_order_ring(data["x"], cfg_thr, mesh=mesh)
 print(f"ring-threshold order == dense ring order: {res_thr.order == res_ring.order}")
 print(
     f"ring-threshold comparisons: {res_thr.comparisons} "
@@ -73,24 +81,25 @@ print(
     f"rounds={res_thr.rounds} converged={res_thr.converged}"
 )
 
-# --- two-level (pod, ring) topology: 2 pods of 4 shards on the 3-axis
-# ("pod", "ring", "model") mesh. Row blocks circulate the intra-pod ring
-# every hop; cross-pod exchanges happen once per intra-pod revolution, and
-# every ppermute for hop k+1 is issued before computing hop k. Orders stay
+# --- two-level (pod, ring) topology, where there are 8 or more devices: 2
+# pods of ring/2 shards. Row blocks circulate the intra-pod ring every hop;
+# cross-pod exchanges happen once per intra-pod revolution, and every
+# ppermute for hop k+1 is issued before computing hop k. Orders stay
 # identical to the flat ring; the device-measured wire counters show the
 # sequential cross-pod rounds dropping below the flat ring's shards/2.
-from repro.launch.mesh import make_ring_mesh
-
-hier_mesh = make_ring_mesh(pods=2, ring=4)
-cfg_hier = ParaLiNGAMConfig(order_backend="ring", min_bucket=8,
-                            ring_topology=(2, 4))
-res_hier = causal_order_ring(data["x"], cfg_hier, mesh=hier_mesh)
-w = res_hier.wire
-print(f"2x4 hier order == flat ring order: {res_hier.order == res_ring.order}")
-print(
-    f"2x4 wire counters: {w['hops_intra']} intra + {w['hops_cross']} "
-    f"cross-pod ppermute rounds, {w['hops_overlapped']} overlapped behind "
-    f"compute (overlap_frac={w['overlap_frac']:.2f}); sequential cross-pod "
-    f"rounds/iter = {w['seq_cross_hops'] // max(len(res_hier.per_iteration), 1)} "
-    f"vs flat ring's {8 // 2}"
-)
+if ring >= 4:
+    hier_mesh = make_ring_mesh(pods=2, ring=ring // 2, model=model)
+    cfg_hier = ParaLiNGAMConfig(order_backend="ring", min_bucket=8,
+                                ring_topology=(2, ring // 2))
+    res_hier = causal_order_ring(data["x"], cfg_hier, mesh=hier_mesh)
+    w = res_hier.wire
+    print(f"2x{ring // 2} hier order == flat ring order: "
+          f"{res_hier.order == res_ring.order}")
+    print(
+        f"2x{ring // 2} wire counters: {w['hops_intra']} intra + "
+        f"{w['hops_cross']} cross-pod ppermute rounds, {w['hops_overlapped']} "
+        f"overlapped behind compute (overlap_frac={w['overlap_frac']:.2f}); "
+        f"sequential cross-pod rounds/iter = "
+        f"{w['seq_cross_hops'] // max(len(res_hier.per_iteration), 1)} "
+        f"vs flat ring's {ring // 2}"
+    )

@@ -12,24 +12,15 @@ configs, data, train, serve, dist, launch, utils.
 
 __version__ = "1.0.0"
 
-# Installed-JAX -> target-API shims (jax.set_mesh, jax.shard_map,
-# jax.sharding.AxisType, make_mesh(axis_types=...)). Must run before any
-# subpackage (or test snippet) builds a mesh; importing anything under
-# ``repro`` goes through here first.
-from repro.dist import compat as _compat
-
-_compat.install()
-del _compat
-
 # Stable public surface. These five names (plus __version__) are the
 # supported API; everything else is internal and may move between releases.
-from repro.core.paralingam import (  # noqa: E402
+from repro.core.paralingam import (
     ParaLiNGAMConfig,
     ParaLiNGAMResult,
     fit,
     fit_batch,
 )
-from repro.serve.async_engine import AsyncLingamEngine  # noqa: E402
+from repro.serve.async_engine import AsyncLingamEngine
 
 __all__ = [
     "AsyncLingamEngine",

@@ -42,7 +42,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.covariance import VAR_EPS, _sample_count, sample_mask
+from repro.core.covariance import _sample_count, sample_mask, scaled_std
 from repro.core.pruning import JITTER_SCALE
 
 
@@ -89,10 +89,12 @@ def adjacency_from_order(x, order, mask=None, n_valid=None,
         mu = jnp.sum(jnp.where(smask, xo, 0.0), axis=1, keepdims=True) / mean_den
         xc = jnp.where(smask, xo - mu, 0.0)
     cov_den = _sample_count(n_valid, n, 1)
-    var = jnp.sum(jnp.square(xc), axis=1) / cov_den
-    std = jnp.sqrt(jnp.maximum(var, VAR_EPS))  # dead rows -> sqrt(VAR_EPS)
-    xs = xc / std[:, None]
-    corr = (xs @ xs.T) / cov_den
+    scaled, std_s, e = scaled_std(xc, cov_den, axis=1)
+    xs = scaled / std_s
+    std = jnp.ldexp(std_s, e)[:, 0]  # dead rows -> sqrt(VAR_EPS)
+    # Full f32 precision (a TPU's default bf16 pass would perturb the
+    # Cholesky factor of a near-singular correlation).
+    corr = jnp.matmul(xs, xs.T, precision=jax.lax.Precision.HIGHEST) / cov_den
 
     p_live = p if mask is None else jnp.sum(mask)
     base = jnp.trace(corr) / jnp.maximum(p_live, 1)

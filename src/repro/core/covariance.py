@@ -48,6 +48,21 @@ def sample_mask(n: int, n_valid):
     return jnp.arange(n) < n_valid
 
 
+def scaled_std(centered, den, axis: int = -1):
+    """Row standard deviation ``sqrt(max(sum(centered^2) / den, VAR_EPS))``
+    without f32 overflow. Each row is first scaled by the power of two ``2^e``
+    that brings its largest magnitude into [0.5, 1): raw rows can be huge
+    (the raw SEM generator's reach ~1e20 at p=770, and their squares sum
+    past the f32 range). Power-of-two scaling is exact, so wherever the unscaled
+    formula does not overflow the results are the same bits. Returns
+    ``(centered / 2^e, std / 2^e, e)``."""
+    _, e = jnp.frexp(jnp.max(jnp.abs(centered), axis=axis, keepdims=True))
+    scaled = jnp.ldexp(centered, -e)
+    var = jnp.sum(jnp.square(scaled), axis=axis, keepdims=True) / den
+    eps = jnp.ldexp(jnp.asarray(VAR_EPS, scaled.dtype), -2 * e)
+    return scaled, jnp.sqrt(jnp.maximum(var, eps)), e
+
+
 def normalize(x, axis: int = -1, ddof: int = 1, n_valid=None):
     """Standardize samples along ``axis`` (zero mean, unit adjusted variance).
 
@@ -66,17 +81,20 @@ def normalize(x, axis: int = -1, ddof: int = 1, n_valid=None):
         xz = jnp.where(smask, x, 0.0)
         mean = jnp.sum(xz, axis=axis, keepdims=True) / mean_den
         centered = jnp.where(smask, x - mean, 0.0)
-    var_den = _sample_count(n_valid, x.shape[axis], ddof)
-    var = jnp.sum(jnp.square(centered), axis=axis, keepdims=True) / var_den
-    return centered / jnp.sqrt(jnp.maximum(var, VAR_EPS))
+    scaled, std, _ = scaled_std(
+        centered, _sample_count(n_valid, x.shape[axis], ddof), axis)
+    return scaled / std
 
 
 def cov_matrix(xn, ddof: int = 1, n_valid=None):
     """Covariance matrix of row-variables ``xn: (p, n)`` (normalized rows ->
     correlation matrix with unit diagonal). Zero-padded sample columns (the
     ``n_valid`` contract of :func:`normalize`) contribute nothing to the dot
-    products, so only the denominator needs the true count."""
-    return (xn @ xn.T) / _sample_count(n_valid, xn.shape[-1], ddof)
+    products, so only the denominator needs the true count. The product runs
+    at full f32 precision: a TPU's default one-pass bf16 matmul would
+    perturb every correlation, and with it every score."""
+    return (jnp.matmul(xn, xn.T, precision=jax.lax.Precision.HIGHEST)
+            / _sample_count(n_valid, xn.shape[-1], ddof))
 
 
 def residual_std(cov_ij):

@@ -649,26 +649,16 @@ def _scan_order_impl(xn, c, gamma0, gamma_growth, block_j: int = 32,
     return order, comps_it, rounds_it, conv_it
 
 
-_scan_order_jit = None
-
-
-def _scan_order(xn, c, gamma0, gamma_growth, **kw):
-    """jit of ``_scan_order_impl``, built lazily so the donation decision
-    reads the backend at first *call* (a module-level ``default_backend()``
-    would force JAX platform init at import time and freeze the choice).
-    xn/c are consumed by the first stage's updates — donate where the
-    backend supports it (donation on CPU trips a spurious warning)."""
-    global _scan_order_jit
-    if _scan_order_jit is None:
-        _scan_order_jit = jax.jit(
-            _scan_order_impl,
-            static_argnames=(
-                "block_j", "backend", "min_bucket",
-                "threshold", "chunk", "max_rounds",
-            ),
-            donate_argnums=(0, 1) if jax.default_backend() != "cpu" else (),
-        )
-    return _scan_order_jit(xn, c, gamma0, gamma_growth, **kw)
+# One dispatch for the whole order. Nothing is donated: no output has the
+# shape of ``xn`` or ``c``, so XLA could alias neither and donation would
+# only warn.
+_scan_order = jax.jit(
+    _scan_order_impl,
+    static_argnames=(
+        "block_j", "backend", "min_bucket",
+        "threshold", "chunk", "max_rounds",
+    ),
+)
 
 
 def _result_from_counters(order, comps_it, rounds_it, conv_it, p: int,
@@ -981,13 +971,14 @@ def _note_backend(cfg: ParaLiNGAMConfig, backend: str) -> None:
         _bump_stat("auto_downgrade")
 
 
-def _run_pipeline(x, cfg: ParaLiNGAMConfig, *, adjacency: bool, batched: bool,
-                  n_valid=None, mask0=None, rules=None,
-                  prune_below: float = 0.0):
+def _pipeline_call(x, cfg: ParaLiNGAMConfig, *, adjacency: bool,
+                   batched: bool, n_valid=None, mask0=None, rules=None,
+                   prune_below: float = 0.0):
+    """The resolved backend, the jitted pipeline and its arguments for one
+    ``fit``/``fit_batch`` dispatch."""
     from repro.kernels import ops as kops
 
     backend = kops.select_backend(cfg, n_valid=n_valid, batched=batched)
-    _note_backend(cfg, backend)
     fn = _pipeline_fn(
         batched, rules if batched else None,
         adjacency=adjacency,
@@ -996,11 +987,35 @@ def _run_pipeline(x, cfg: ParaLiNGAMConfig, *, adjacency: bool, batched: bool,
         min_bucket=cfg.min_bucket, chunk=cfg.chunk, max_rounds=cfg.max_rounds,
         prune_below=prune_below,
     )
-    return fn(
+    args = (
         jnp.asarray(x, cfg.dtype),
         jnp.asarray(cfg.gamma0, cfg.dtype), jnp.asarray(cfg.gamma_growth, cfg.dtype),
         n_valid, mask0,
     )
+    return backend, fn, args
+
+
+def _run_pipeline(x, cfg: ParaLiNGAMConfig, **kw):
+    backend, fn, args = _pipeline_call(x, cfg, **kw)
+    _note_backend(cfg, backend)
+    return fn(*args)
+
+
+def lower_fit(x, config: ParaLiNGAMConfig | None = None,
+              prune_below: float = 0.0):
+    """The one-dispatch program :func:`fit` runs on ``x`` under ``config``,
+    lowered (a ``jax.stages.Lowered``). ``.compile().as_text()`` shows what
+    the device runs, for example whether the score kernel is a native TPU
+    custom call. That compile is separate from ``fit``'s own, which reuses
+    it only through the persistent compilation cache. The ring driver is
+    several dispatches and has no such single program."""
+    cfg = config or ParaLiNGAMConfig()
+    if resolve_order_backend(cfg) == "ring":
+        raise ConfigError("lower_fit covers the one-dispatch pipeline; the "
+                          "ring driver has no single program")
+    _, fn, args = _pipeline_call(x, cfg, adjacency=True, batched=False,
+                                 prune_below=prune_below)
+    return fn.lower(*args)
 
 
 def fit(x, config: ParaLiNGAMConfig | None = None, prune_below: float = 0.0,

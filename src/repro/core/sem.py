@@ -24,6 +24,11 @@ class SemSpec:
     density: str = "sparse"  # "sparse" | "dense"
     seed: int = 0
     noise_scale: float = 1.0
+    standardize: bool = False  # rescale each variable to unit variance as
+    #   it is generated (b_true follows). Without it the variance compounds
+    #   along the graph: at p=770 "sparse" raw rows reach a standard
+    #   deviation of ~1e20 and their correlation matrix is singular in
+    #   float64, so no float32 fit is well posed there.
 
 
 def random_adjacency(p: int, density: str, rng: np.random.Generator) -> np.ndarray:
@@ -73,6 +78,10 @@ def generate(spec: SemSpec):
     x = np.zeros_like(noise)
     for i in range(spec.p):
         x[i] = b[i, :i] @ x[:i] + noise[i]
+        if spec.standardize:
+            s = x[i].std()
+            x[i] /= s
+            b[i] /= s
     perm = rng.permutation(spec.p)
     # variable originally at row i now sits at row perm[i]
     x_perm = np.empty_like(x)

@@ -1,11 +1,6 @@
-"""Distributed layer: sharding rules, the ppermute ring (find-root and the
-full ring-driven causal order), and JAX API compatibility shims.
-
-Import order matters: ``repro/__init__`` — which always runs before this
-package — installs the compat shims (``repro.dist.compat.install``) so the
-newer-JAX surface (``jax.set_mesh``, ``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(axis_types=...)``) exists before
-any model/test code touches it.
+"""Distributed layer: sharding rules and the ppermute ring (find-root and
+the full ring-driven causal order). Meshes come from ``jax.make_mesh``; the
+ring driver picks up the mesh made active by ``jax.set_mesh``.
 """
 
 from repro.dist.sharding import NO_SHARDING, ShardingRules, make_rules

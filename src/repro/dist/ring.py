@@ -653,8 +653,6 @@ def ring_find_root(xn, c, mask, mesh, row_axes: tuple | None = None,
     x_spec = P(row_axes, sample_axis)
 
     ring_sizes = tuple(sizes[a] for a in ring_axes)
-    # jax.shard_map is the compat-installed surface on 0.4.x and the real
-    # API on newer JAX (where jax.experimental.shard_map no longer exists).
     body = jax.shard_map(
         lambda x, cm, mk: _ring_body(
             x, cm, mk, ring_axes=ring_axes, ring_sizes=ring_sizes,
@@ -703,4 +701,10 @@ def ring_find_root_jit(mesh, score_backend: str = "auto",
         return ring_find_root(xn, c, mask, hier, row_axes=row_axes,
                               score_backend=score_backend)
 
-    return fn
+    def call(xn, c, mask):
+        # shard_map wants the context mesh to be the one it maps over, so
+        # ``hier`` stands in for any ``jax.set_mesh`` mesh of the caller.
+        with jax.set_mesh(hier):
+            return fn(xn, c, mask)
+
+    return call

@@ -291,10 +291,8 @@ def _canonical_mesh(mesh, n: int, pods: int | None = None):
     count — the caller turns an explicit-topology mismatch into a
     ``ConfigError``."""
     if mesh is None:
-        from repro.dist import compat
-
-        mesh = compat.current_mesh()
-    if mesh is None:
+        mesh = jax.sharding.get_mesh()  # the ``jax.set_mesh`` mesh, if any
+    if mesh.empty:
         devs = np.array(jax.devices())
         msize = 1
         mesh_pods = 1
@@ -376,7 +374,10 @@ def causal_order_ring(x, config=None, mesh=None):
         gamma0=float(cfg.gamma0), gamma_growth=float(cfg.gamma_growth),
         max_rounds=cfg.max_rounds,
     )
-    order, comps_it, rounds_it, conv_it, hops_it = run(xn, c)
+    # shard_map wants the context mesh to be the one it maps over, so the
+    # canonical mesh stands in for the caller's ``jax.set_mesh`` mesh here.
+    with jax.set_mesh(canon):
+        order, comps_it, rounds_it, conv_it, hops_it = run(xn, c)
     return _result_from_counters(order, comps_it, rounds_it, conv_it, p,
                                  cfg.max_rounds, hops_it=hops_it,
                                  topology=(pods, big_r))

@@ -94,7 +94,7 @@ def select_backend(cfg, n_valid=None, batched: bool = False) -> str:
     return "pallas_fused" if _on_tpu() else "xla"
 
 
-def pairwise_moments(xi, xj, c, *, block_i: int = 8, block_j: int = 8,
+def pairwise_moments(xi, xj, c, *, block_i: int = 8, block_j: int = 128,
                      block_n: int = 512):
     """Raw Hyvarinen moment sums of the (i, j) residual streams via the
     square moments kernel — ``(m1_sum, m2_sum)``, each (pi, pj), no ``1/n``,
@@ -109,7 +109,7 @@ def pairwise_moments(xi, xj, c, *, block_i: int = 8, block_j: int = 8,
     )
 
 
-def residual_entropy_matrix(xn, c, *, block_i: int = 8, block_j: int = 8,
+def residual_entropy_matrix(xn, c, *, block_i: int = 8, block_j: int = 128,
                             block_n: int = 512, n_valid=None):
     """HR matrix via the moments kernel + jnp entropy epilogue."""
     return _pairwise.pairwise_score(

@@ -22,7 +22,9 @@ finished entropies is what makes the kernel compose with
     the ``psum_axis`` contract of ``pairwise.stream_moments``.
 
 TPU considerations:
-  * BN is a multiple of 128 (VPU lane width); BI/BJ multiples of 8 (sublanes).
+  * BN is a multiple of 128 (VPU lane width), BI a multiple of 8 (sublanes);
+    the (BI, BJ) correlation/output tiles put BJ on lanes, so BJ is 128 or
+    the whole (8-rounded) column count.
   * The workload is transcendental-heavy (log1p/exp) -> VPU-bound, no MXU
     use; arithmetic intensity grows with BI*BJ/(BI+BJ), so larger pair tiles
     directly buy HBM-bandwidth headroom (block-shape sweep in
@@ -41,6 +43,19 @@ from jax.experimental import pallas as pl
 
 from repro.core.covariance import VAR_EPS, _sample_count
 from repro.core.entropy import entropy_from_moments, log_cosh, u_exp_moment
+
+
+def require_tpu_tiling(**blocks) -> None:
+    """Refuse block sizes the TPU kernel compiler (Mosaic) would refuse, in
+    interpret mode too, so a CPU parity test cannot pass at a tiling the
+    chip rejects. Each name ending in ``_n`` is a lane block (a multiple of
+    128); the rest are sublane blocks (a multiple of 8)."""
+    for name, size in blocks.items():
+        unit = 128 if name.endswith("_n") else 8
+        if size % unit:
+            raise ValueError(
+                f"{name}={size} is not a multiple of {unit}: the TPU kernel "
+                "compiler needs (8, 128)-aligned blocks")
 
 
 def _pairwise_moments_kernel(nk, xi_ref, xj_ref, c_ref, m1_ref, m2_ref):
@@ -72,7 +87,7 @@ def pairwise_moments(
     c,
     *,
     block_i: int = 8,
-    block_j: int = 8,
+    block_j: int = 128,
     block_n: int = 512,
     interpret: bool = False,
 ):
@@ -83,9 +98,21 @@ def pairwise_moments(
     ``(m1_sum, m2_sum)``, each (pi, pj) float32, with
     ``m1_sum[a, b] = sum_k log cosh u_ab[k]`` over the sample axis — no
     ``1/n``, no entropy. Finalize with ``pairwise.finalize_moments`` (which
-    owns the ``n_valid`` denominator and the ``psum_axis`` combine)."""
+    owns the ``n_valid`` denominator and the ``psum_axis`` combine).
+
+    Mosaic wants the last two dims of every block (8, 128)-aligned or equal
+    to the array's: a column block wider than the 8-rounded ``pj`` becomes
+    that whole (padded) dim, and ``block_n`` shrinks to the 128-rounded
+    sample count."""
     pi, n = xi.shape
     pj = xj.shape[0]
+    block_j = min(block_j, pj + (-pj) % 8)
+    block_n = min(block_n, n + (-n) % 128)
+    require_tpu_tiling(block_i=block_i, block_n=block_n)
+    if block_j % 128 and block_j != pj + (-pj) % 8:
+        raise ValueError(
+            f"block_j={block_j} must be a multiple of 128 or cover all "
+            f"{pj} columns: it is the lane dim of the correlation tile")
     pi_pad = pi + (-pi) % block_i
     pj_pad = pj + (-pj) % block_j
     n_pad = n + (-n) % block_n
@@ -125,7 +152,7 @@ def pairwise_score(
     c,
     *,
     block_i: int = 8,
-    block_j: int = 8,
+    block_j: int = 128,
     block_n: int = 512,
     interpret: bool = False,
     n_valid=None,

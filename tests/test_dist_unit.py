@@ -104,25 +104,29 @@ def test_make_rules_batch_axes_override():
 
 
 # ---------------------------------------------------------------------------
-# compat shims
+# the mesh made active by jax.set_mesh reaches the ring driver
 # ---------------------------------------------------------------------------
 
 
-def test_set_mesh_context_and_plain_call():
-    from repro.dist import compat
+def test_canonical_mesh_reads_set_mesh():
+    """``_canonical_mesh(None, ...)`` takes the ``jax.set_mesh`` mesh (its
+    ``model`` axis included) while one is active, and all devices once it
+    is gone. One device here; the multi-device form is in
+    ``test_distributed.py``."""
+    from repro.dist.ring_order import _canonical_mesh
 
-    mesh = jax.make_mesh((1,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with jax.set_mesh(mesh):
-        assert jax.sharding.get_abstract_mesh() is mesh
-    assert compat.current_mesh() is None
-    # plain call = the real API's global set: mesh stays active afterwards
-    ctx = jax.set_mesh(mesh)
-    try:
-        assert jax.sharding.get_abstract_mesh() is mesh
-    finally:
-        ctx.__exit__(None, None, None)
-    assert compat.current_mesh() is None
+        assert jax.sharding.get_mesh() == mesh
+        canon, pods, big_r, _ = _canonical_mesh(None, n=64)
+        assert list(canon.devices.flat) == list(mesh.devices.flat)
+        assert (pods, big_r) == (1, 1)
+    assert jax.sharding.get_mesh().empty
+    canon, pods, big_r, sample_axis = _canonical_mesh(None, n=64)
+    assert canon.axis_names == ("pod", "ring", "model")
+    assert canon.devices.size == len(jax.devices())
+    assert sample_axis is None
 
 
 # ---------------------------------------------------------------------------

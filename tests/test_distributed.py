@@ -49,6 +49,37 @@ def test_ring_find_root_matches_dense():
     assert "RING_OK" in out
 
 
+def test_ring_driver_reads_set_mesh():
+    """``fit(order_backend="ring")`` inside ``with jax.set_mesh(mesh)`` rings
+    over that mesh — here 2 of the 8 devices, with samples sharded over its
+    ``model`` axis — and over all 8 devices once no mesh is active."""
+    out = _run("""
+    import jax, numpy as np
+    from repro.core import direct_lingam, sem
+    from repro.core.paralingam import ParaLiNGAMConfig, fit
+    from repro.dist.ring_order import _canonical_mesh
+
+    mesh = jax.make_mesh((1, 2, 2), ("pod", "ring", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3,
+                         devices=jax.devices()[:4])
+    x = sem.generate(sem.SemSpec(p=16, n=512, seed=3))["x"]
+    cfg = ParaLiNGAMConfig(order_backend="ring", min_bucket=8)
+    with jax.set_mesh(mesh):
+        canon, pods, big_r, sample_axis = _canonical_mesh(None, 512)
+        assert list(canon.devices.flat) == list(mesh.devices.flat)
+        assert (pods, big_r, sample_axis) == (1, 2, "model"), (pods, big_r)
+        res, _ = fit(x, cfg)
+    assert res.wire["ring"] == 2, res.wire
+    assert _canonical_mesh(None, 512)[2] == 8
+    res8, _ = fit(x, cfg)
+    assert res8.wire["ring"] == 8, res8.wire
+    ref = direct_lingam.causal_order(np.asarray(x, np.float64))
+    assert res.order == res8.order == list(ref), (res.order, res8.order, ref)
+    print("SET_MESH_OK")
+    """)
+    assert "SET_MESH_OK" in out
+
+
 def test_sharded_train_step_runs():
     """A real (allocating) sharded train step on a 4x2 mesh — smoke config."""
     out = _run("""

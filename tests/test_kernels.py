@@ -26,7 +26,10 @@ def test_pairwise_score_matches_ref(p, n):
     )
 
 
-@pytest.mark.parametrize("block", [(8, 8, 128), (8, 16, 256), (16, 8, 512)])
+# Block shapes the TPU kernel compiler takes: block_i a multiple of 8,
+# block_n of 128, block_j a multiple of 128 or the whole 8-rounded column
+# count (24 here; 128 is clamped to it).
+@pytest.mark.parametrize("block", [(8, 128, 128), (8, 24, 256), (16, 128, 512)])
 def test_pairwise_score_block_shapes(block):
     bi, bj, bn = block
     rng = np.random.default_rng(0)

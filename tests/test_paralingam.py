@@ -149,3 +149,30 @@ def test_kernel_backed_dense_matches():
     r1 = causal_order(data["x"], ParaLiNGAMConfig(order_backend="host", score_backend="xla"))
     r2 = causal_order(data["x"], ParaLiNGAMConfig(order_backend="host", score_backend="pallas"))
     assert r1.order == r2.order
+
+
+def test_fit_is_scale_free_past_f32_square_range():
+    """Rows of magnitude ~1e21 square past the f32 range; the row
+    statistics scale by powers of two first, so the order is unchanged and
+    B is finite and follows the scaling."""
+    data = _data(p=8, n=4000, seed=11)
+    big = data["x"] * 2.0 ** np.arange(62, 70)[:, None]
+    res, b = fit(data["x"])
+    res_big, b_big = fit(big)
+    assert res_big.order == res.order
+    scale = 2.0 ** (np.arange(62, 70)[:, None] - np.arange(62, 70)[None, :])
+    np.testing.assert_allclose(np.asarray(b_big), np.asarray(b) * scale,
+                               rtol=1e-4, atol=1e-6)
+    xn = normalize(jnp.asarray(data["x"], jnp.float32))
+    xn_big = normalize(jnp.asarray(big, jnp.float32))
+    np.testing.assert_array_equal(np.asarray(xn_big), np.asarray(xn))
+
+
+def test_standardized_sem_recovers_strengths():
+    """``SemSpec.standardize`` keeps every variable at unit variance, and
+    ``b_true`` follows it: the fit recovers it like the raw SEM's."""
+    data = sem.generate(sem.SemSpec(p=8, n=8000, seed=11, standardize=True))
+    np.testing.assert_allclose(data["x"].std(axis=1), 1.0, rtol=1e-12)
+    res, b = fit(data["x"])
+    assert sem.is_valid_causal_order(res.order, data["b_true"])
+    np.testing.assert_allclose(b, data["b_true"], atol=0.12)
