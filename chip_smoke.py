@@ -41,14 +41,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 #: the largest score: f32 moment sums over n=10^4 samples, summed in a
 #: different order by the kernel and by XLA, then squared into the score.
 SCORE_RTOL = 1e-3
-#: Absolute error allowed in one pair statistic I_ij: an entropy difference
-#: of order 1 from f32 moment sums over ~10^4 samples, after the rank-1
-#: updates of earlier iterations. A score is S = sum_j min(0, I_ij)^2, so by
-#: the triangle inequality sqrt(S) moves by at most TIE_EPS * sqrt(live
-#: variables) under such errors. Two orders may part only at a near-tie:
-#: the candidates' sqrt-scores (``xla``, on the state both orders share)
-#: differ by at most twice that.
-TIE_EPS = 1e-5
+#: How far one state's sqrt-scores may move between two score backends, per
+#: sqrt(live variables). A score is S_i = sum_j min(0, I_ij)^2, so sqrt(S_i)
+#: is 1-Lipschitz in the pair statistics I_ij: an error of at most TIE_EPS in
+#: each moves it by at most TIE_EPS * sqrt(live). Two orders may part only
+#: at a near-tie: the candidates' sqrt-scores (``xla``, on the state both
+#: orders share) differ by at most twice that. Set with a margin over the
+#: kernel-vs-``xla`` error of the first iteration at IJR904 on a v5e (PERF.md,
+#: Findings, PR 12); the fit phase measures that error on every run and fails
+#: where it exceeds TIE_EPS.
+TIE_EPS = 1e-6
 #: Strengths ``B`` of two fits with the same order agree to this, relative
 #: to the largest entry.
 B_RTOL = 1e-3
@@ -56,8 +58,8 @@ B_RTOL = 1e-3
 IJR904 = (770, 10_000)  # configs/lingam.py, ParaLiNGAM Table 1
 #: The four-chip check runs at FIG4_P1000 (configs/lingam.py, the paper's
 #: Fig. 4), not IML1515 (p=2326): IML1515's one-chip scan reference makes
-#: ~2300 score sweeps of up to 1.48 s (the 4096 bucket at n=10^4 on a v5e),
-#: which would hold all four chips for ~14 minutes.
+#: ~2300 score sweeps, 278 of them at the 4096 bucket (16x the work of a
+#: 1024-bucket sweep at n=10^4), and it holds all four chips while it runs.
 FIG4_P1000 = (1000, 8192)
 ECOLI_CORE = (85, 10_000)
 QUICKSTART = (12, 5000, 42)  # examples/quickstart.py: p, n, seed
@@ -134,13 +136,25 @@ def score_gap(x, got, ref):
         xn, c, mask = _update_iteration(xn, c, jnp.int32(root), mask)
     _, s = find_root_dense(xn, c, mask, score_backend="xla")
     sa, sb = float(s[got[k]]), float(s[ref[k]])
-    gap = abs(np.sqrt(sa) - np.sqrt(sb))
-    bound = 2 * TIE_EPS * np.sqrt(len(ref) - k)
+    gap, bound = tie_gap(sa, sb, len(ref) - k)
     desc = (f"first diverge at iteration {k} of {len(ref)}: "
             f"{got[k]} vs {ref[k]}, scores {sa:.6e} vs {sb:.6e}, "
             f"sqrt-score gap {gap:.3e} (near-tie bound {bound:.3e})")
     check(gap <= bound, f"orders diverge beyond a near-tie: {desc}")
     return desc
+
+
+def tie_gap(sa: float, sb: float, live: int):
+    """The sqrt-score gap of two candidate roots' scores among ``live``
+    variables, and the largest gap a near-tie may have (``TIE_EPS``)."""
+    return abs(np.sqrt(sa) - np.sqrt(sb)), 2 * TIE_EPS * np.sqrt(live)
+
+
+def sqrt_score_err(s_got, s_ref) -> float:
+    """Largest sqrt-score difference of two score vectors of one state, per
+    sqrt(live variables): the quantity ``TIE_EPS`` bounds."""
+    return float(np.abs(np.sqrt(s_got) - np.sqrt(s_ref)).max()
+                 / np.sqrt(len(s_ref)))
 
 
 def _tally(rules) -> str:
@@ -166,7 +180,8 @@ def has_kernel(x, config) -> bool:
 
 
 def phase_fit(p: int, n: int, seed: int, *, expect_kernel: bool = True):
-    """Dense and threshold fits, default config, against ``xla``."""
+    """Dense and threshold fits, default config, against ``xla``; and the
+    first iteration's kernel-vs-``xla`` error on the raw SEM, reported."""
     from repro import ParaLiNGAMConfig, fit
 
     x = make_data(p, n, seed)["x"]
@@ -186,6 +201,9 @@ def phase_fit(p: int, n: int, seed: int, *, expect_kernel: bool = True):
     s_err = float(np.abs(s_k - s_x).max() / np.abs(s_x).max())
     check(s_err <= SCORE_RTOL,
           f"first-iteration scores differ by {s_err:.3e} (> {SCORE_RTOL})")
+    eps = sqrt_score_err(s_k, s_x)
+    check(eps <= TIE_EPS, f"first-iteration sqrt-score error {eps:.3e} per "
+                          f"sqrt(live) exceeds TIE_EPS={TIE_EPS}")
     t0 = time.perf_counter()
     res, b = fit(x, ParaLiNGAMConfig())
     np.asarray(b)
@@ -195,10 +213,20 @@ def phase_fit(p: int, n: int, seed: int, *, expect_kernel: bool = True):
     check(np.isfinite(np.asarray(b)).all(), "B has non-finite entries")
     lines.append(
         f"fit dense p={p} n={n}: tpu_custom_call={kernel} "
-        f"first-iteration score rel err {s_err:.3e} (<= {SCORE_RTOL}); "
+        f"first-iteration score rel err {s_err:.3e} (<= {SCORE_RTOL}), "
+        f"sqrt-score err per sqrt(live) {eps:.3e} (<= TIE_EPS {TIE_EPS}); "
         f"order vs xla: {rule}; B rel err "
         f"{'n/a' if b_err is None else f'{b_err:.3e}'}; B finite; "
         f"wall s (compile included) default {t_def:.1f}, xla {t_ref:.1f}")
+
+    x_raw = make_data(p, n, seed, standardize=False)["x"]
+    r_k, r_x = first_scores(x_raw, "auto"), first_scores(x_raw, "xla")
+    lines.append(
+        f"raw SEM p={p} n={n} (not standardized; reported, not checked): "
+        f"first-iteration score rel err "
+        f"{np.abs(r_k - r_x).max() / np.abs(r_x).max():.3e}, sqrt-score err "
+        f"per sqrt(live) {sqrt_score_err(r_k, r_x):.3e}, largest score "
+        f"{r_x.max():.3e}")
 
     t0 = time.perf_counter()
     thr, b_thr = fit(x, ParaLiNGAMConfig(threshold=True))
@@ -285,7 +313,8 @@ def phase_quickstart(p: int, n: int, seed: int):
 
 def phase_ring(p: int, n: int, seed: int, chips: int = 4):
     """The messaging ring over ``chips`` devices, dense and threshold,
-    against the one-chip scan order of the same data."""
+    against the one-chip scan order of the same data. Yields each line as
+    soon as it is known, so a run cut short still shows what finished."""
     import jax
 
     from repro import ParaLiNGAMConfig, fit
@@ -293,39 +322,33 @@ def phase_ring(p: int, n: int, seed: int, chips: int = 4):
     from repro.launch.mesh import make_ring_mesh
 
     x = make_data(p, n, seed)["x"]
-    lines = []
-    runs = {}
-    with jax.set_mesh(make_ring_mesh(1, chips, 1)):
-        for thr in (False, True):
-            t0 = time.perf_counter()
-            res, b = fit(x, ParaLiNGAMConfig(order_backend="ring", threshold=thr))
-            check(np.isfinite(np.asarray(b)).all(), "ring B is not finite")
-            runs[thr] = (res, time.perf_counter() - t0)
-            if not thr:
-                mem = [d.memory_stats() for d in jax.devices()[:chips]]
-                lines.append(
-                    "ring memory per device (bytes_in_use / peak_bytes_in_use): "
-                    + ", ".join(f"{i}: {m['bytes_in_use']} / "
-                                f"{m['peak_bytes_in_use']}"
-                                for i, m in enumerate(mem)))
-                block = 4 * x.size // chips  # one f32 row block of the data
-                check(all(m["peak_bytes_in_use"] >= block for m in mem),
-                      f"a device peaked below one row block ({block} bytes): "
-                      "the ring did not spread its blocks")
     t0 = time.perf_counter()
     scan = causal_order(x, ParaLiNGAMConfig(order_backend="scan"))
-    t_scan = time.perf_counter() - t0
-    for thr, (res, dt) in runs.items():
+    yield (f"one-chip scan reference p={p} n={n}: wall s (compile included) "
+           f"{time.perf_counter() - t0:.1f}")
+    mesh = make_ring_mesh(1, chips, 1)
+    for thr in (False, True):
+        t0 = time.perf_counter()
+        with jax.set_mesh(mesh):
+            res, b = fit(x, ParaLiNGAMConfig(order_backend="ring", threshold=thr))
+            check(np.isfinite(np.asarray(b)).all(), "ring B is not finite")
+        dt = time.perf_counter() - t0
+        if not thr:
+            mem = [d.memory_stats() for d in mesh.devices.flat]
+            yield ("ring memory per device (bytes_in_use / peak_bytes_in_use): "
+                   + ", ".join(f"{i}: {m['bytes_in_use']} / "
+                               f"{m['peak_bytes_in_use']}"
+                               for i, m in enumerate(mem)))
+            block = 4 * x.size // chips  # one f32 row block of the data
+            check(all(m["peak_bytes_in_use"] >= block for m in mem),
+                  f"a device peaked below one row block ({block} bytes): "
+                  "the ring did not spread its blocks")
         check(res.wire["ring"] == chips, f"ring ran on {res.wire} not {chips}")
         rule = score_gap(x, res.order, scan.order)
-        lines.append(
-            f"ring {'threshold' if thr else 'dense'} p={p} n={n} on "
-            f"{res.wire['ring']} chips: order vs one-chip scan: {rule}; "
-            f"comparisons saved vs serial {100 * res.saving_vs_serial:.2f}%; "
-            f"wall s (compile included) {dt:.1f}")
-    lines.append(f"one-chip scan reference: wall s (compile included) "
-                 f"{t_scan:.1f}")
-    return lines
+        yield (f"ring {'threshold' if thr else 'dense'} p={p} n={n} on "
+               f"{res.wire['ring']} chips: order vs one-chip scan: {rule}; "
+               f"comparisons saved vs serial {100 * res.saving_vs_serial:.2f}%; "
+               f"wall s (compile included) {dt:.1f}")
 
 
 def main(argv=None) -> int:

@@ -23,19 +23,15 @@ from repro.core.paralingam import ParaLiNGAMConfig, causal_order_scan, find_root
 from repro.core.sem import SemSpec, generate
 from repro.dist.ring import ring_find_root_jit
 from repro.dist.ring_order import causal_order_ring
+from repro.launch.mesh import make_ring_mesh
 
 n_dev = 1 << (len(jax.devices()).bit_length() - 1)  # largest power of two
 model = 2 if n_dev >= 8 else 1
 ring = n_dev // model
 
 
-def make_ring_mesh(pods, ring, model):
-    return jax.make_mesh((pods, ring, model), ("pod", "ring", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3,
-                         devices=jax.devices()[:n_dev])
-
-
-mesh = make_ring_mesh(pods=1, ring=ring, model=model)
+devices = jax.devices()[:n_dev]
+mesh = make_ring_mesh(pods=1, ring=ring, model=model, devices=devices)
 print("mesh:", dict(zip(mesh.axis_names, mesh.devices.shape)))
 
 data = generate(SemSpec(p=64, n=4096, seed=1))
@@ -88,7 +84,8 @@ print(
 # identical to the flat ring; the device-measured wire counters show the
 # sequential cross-pod rounds dropping below the flat ring's shards/2.
 if ring >= 4:
-    hier_mesh = make_ring_mesh(pods=2, ring=ring // 2, model=model)
+    hier_mesh = make_ring_mesh(pods=2, ring=ring // 2, model=model,
+                               devices=devices)
     cfg_hier = ParaLiNGAMConfig(order_backend="ring", min_bucket=8,
                                 ring_topology=(2, ring // 2))
     res_hier = causal_order_ring(data["x"], cfg_hier, mesh=hier_mesh)

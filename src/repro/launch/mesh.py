@@ -28,13 +28,15 @@ def make_local_mesh(data: int = 1, model: int = 1):
     )
 
 
-def make_ring_mesh(pods: int = 1, ring: int = 1, model: int = 1):
+def make_ring_mesh(pods: int = 1, ring: int = 1, model: int = 1,
+                   devices=None):
     """3-axis ``("pod", "ring", "model")`` mesh for the two-level messaging
     ring (``dist.ring_order``): P pods of R intra-pod shards, samples over
     ``model``. ``pods=1`` is the flat ring with a degenerate pod axis —
     ``dist.sharding.make_rules`` and ``dist.ring.ring_find_root_jit`` both
-    consume the mesh without flattening the pod level away."""
+    consume the mesh without flattening the pod level away. ``devices``
+    (default: all of them) must number ``pods * ring * model``."""
     return jax.make_mesh(
         (pods, ring, model), ("pod", "ring", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 3,
+        axis_types=(jax.sharding.AxisType.Auto,) * 3, devices=devices,
     )

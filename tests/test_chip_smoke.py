@@ -27,8 +27,9 @@ def test_refuses_without_tpu(smoke, argv, capsys):
 
 
 def test_phase_fit_small(smoke):
-    dense, thr = smoke.phase_fit(24, 2000, 0, expect_kernel=False)
+    dense, raw, thr = smoke.phase_fit(24, 2000, 0, expect_kernel=False)
     assert "order vs xla: identical" in dense
+    assert "sqrt-score err per sqrt(live)" in dense and "raw SEM" in raw
     assert "fit threshold" in thr and "saved" in thr
 
 
@@ -65,3 +66,39 @@ def test_close_b_bounds(smoke):
     assert smoke.close_b(b, b) == 0.0
     with pytest.raises(smoke.SmokeError):
         smoke.close_b(b * 1.1, b)
+
+
+def test_score_gap_refuses_a_1_5x_divergence(smoke):
+    """Orders that part where the two candidates' first-iteration scores
+    differ by about 1.5x are no near-tie."""
+    x = smoke.make_data(24, 2000, 0)["x"]
+    s = smoke.first_scores(x, "xla")
+    pos = [int(v) for v in np.flatnonzero(s > 0)]
+    a, b = min(((a, b) for a in pos for b in pos if a != b),
+               key=lambda ab: abs(s[ab[1]] / s[ab[0]] - 1.5))
+    assert 1.4 <= s[b] / s[a] <= 1.6
+    rest = [v for v in range(24) if v not in (a, b)]
+    with pytest.raises(smoke.SmokeError, match="beyond a near-tie"):
+        smoke.score_gap(x, [b, a] + rest, [a, b] + rest)
+
+
+@pytest.mark.parametrize("score,live", [(1.352185e-07, 53), (7.057e-03, 64)],
+                         ids=["late_p85", "first_p64"])
+def test_tie_gap_refuses_1_5x_scores(smoke, score, live):
+    """At the score levels seen late in a p=85 order on the chip and in the
+    first iteration at p=64, a 1.5x score gap is beyond the near-tie bound."""
+    gap, bound = smoke.tie_gap(1.5 * score, score, live)
+    assert gap > bound
+
+
+def test_tie_gap_admits_the_chip_near_tie(smoke):
+    """The fit_batch divergence a v5e showed (iteration 32 of 85) is within
+    the bound."""
+    gap, bound = smoke.tie_gap(1.354709e-07, 1.352185e-07, 85 - 32)
+    assert gap <= bound
+
+
+def test_sqrt_score_err(smoke):
+    s = np.array([4.0, 9.0, 0.0, 1.0])
+    assert smoke.sqrt_score_err(s, s) == 0.0
+    assert smoke.sqrt_score_err(s + np.array([0, 0, 0.25, 0]), s) == 0.25

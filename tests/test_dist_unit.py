@@ -129,6 +129,19 @@ def test_canonical_mesh_reads_set_mesh():
     assert sample_axis is None
 
 
+def test_make_ring_mesh_over_given_devices():
+    """``launch.mesh.make_ring_mesh`` spans all devices by default and the
+    ones it is given otherwise (a power-of-two subset of a host's chips)."""
+    from repro.launch.mesh import make_ring_mesh
+
+    n = len(jax.devices())
+    mesh = make_ring_mesh(1, n, 1)
+    assert mesh.axis_names == ("pod", "ring", "model")
+    assert dict(mesh.shape) == {"pod": 1, "ring": n, "model": 1}
+    one = make_ring_mesh(1, 1, 1, devices=jax.devices()[-1:])
+    assert list(one.devices.flat) == [jax.devices()[-1]]
+
+
 # ---------------------------------------------------------------------------
 # ring schedule (pure)
 # ---------------------------------------------------------------------------

@@ -53,10 +53,16 @@ def _compiles_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("p", [770, 4096], ids=["ijr904", "iml1515_bucket"])
+@pytest.mark.parametrize("p", [770, 4096, 8192],
+                         ids=["ijr904", "iml1515_bucket", "bucket_8192"])
 def test_fused_score_vector_compiles(shape, p):
-    """IJR904 (p=770) and the bucket the scan driver gives IML1515
-    (p=2326 -> 4096), whose 130816-tile map must fit scalar memory."""
+    """IJR904 (p=770), the bucket the scan driver gives IML1515
+    (p=2326 -> 4096), whose 130816-tile map must fit scalar memory, and
+    the next bucket, where the tile map only fits once ``tri_block`` has
+    doubled the row block past 8."""
+    from repro.kernels.fused_score import tri_block
+
+    assert (tri_block(p, 8) > 8) == (p > 4096)
     _compiles_kernel(
         lambda x, c, m: fused_score_vector(x, c, m, interpret=False),
         shape((p, N)), shape((p, p)), shape((p,), jnp.bool_))
