@@ -50,9 +50,9 @@ as the dense path by the paper's Section 3.2 correctness argument.
 
 from __future__ import annotations
 
-import threading
 import time
 import warnings
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from functools import lru_cache, partial
 
@@ -75,6 +75,7 @@ from repro.core.pairwise import (
     scores_from_stats,
 )
 from repro.core.pairwise import residual_entropy_matrix as _hr_jnp
+from repro.utils import tracing
 from repro.utils.schedule import make_schedule
 from repro.utils.shapes import next_pow2
 
@@ -602,44 +603,48 @@ def _scan_order_impl(xn, c, gamma0, gamma_growth, block_j: int = 32,
             # Compaction: pack live rows first; the live count is derived on
             # device (== the static p - pos when mask0 is None, fewer when a
             # padded dataset started with dead rows).
-            live = jnp.sum(mloc)
-            sel = jnp.nonzero(mloc, size=m, fill_value=0)[0].astype(jnp.int32)
-            idx_g = idx_g[sel]
-            xb = xb[sel]
-            cb = cb[sel][:, sel]
-            mloc = jnp.arange(m) < live
+            with jax.named_scope("compact"):
+                live = jnp.sum(mloc)
+                sel = jnp.nonzero(mloc, size=m, fill_value=0)[0].astype(jnp.int32)
+                idx_g = idx_g[sel]
+                xb = xb[sel]
+                cb = cb[sel][:, sel]
+                mloc = jnp.arange(m) < live
             m_cur = m
 
         def body(k, st, idx_g=idx_g, pos=pos, m=m):
             xb, cb, ml, order, comps_it, rounds_it, conv_it = st
-            if threshold:
-                root_l, _, comps, rounds, conv = _find_root_threshold_impl(
-                    xb, cb, ml, gamma0, gamma_growth,
-                    chunk=min(chunk, m), max_rounds=max_rounds, n_valid=n_valid,
-                )
-            else:
-                root_l, _ = _find_root_dense_impl(
-                    xb, cb, ml, block_j=min(block_j, m),
-                    backend=backend, n_valid=n_valid,
-                )
-                r = jnp.sum(ml).astype(cdtype)  # live rows this iteration
-                comps = r * (r - 1) // 2
-                rounds = jnp.asarray(0, jnp.int32)
-                conv = jnp.asarray(True)
+            with jax.named_scope("find_root"):
+                if threshold:
+                    root_l, _, comps, rounds, conv = _find_root_threshold_impl(
+                        xb, cb, ml, gamma0, gamma_growth,
+                        chunk=min(chunk, m), max_rounds=max_rounds,
+                        n_valid=n_valid,
+                    )
+                else:
+                    root_l, _ = _find_root_dense_impl(
+                        xb, cb, ml, block_j=min(block_j, m),
+                        backend=backend, n_valid=n_valid,
+                    )
+                    r = jnp.sum(ml).astype(cdtype)  # live rows this iteration
+                    comps = r * (r - 1) // 2
+                    rounds = jnp.asarray(0, jnp.int32)
+                    conv = jnp.asarray(True)
             it = pos + k
             order = order.at[it].set(idx_g[root_l])
             comps_it = comps_it.at[it].set(comps)
             rounds_it = rounds_it.at[it].set(rounds.astype(jnp.int32))
             conv_it = conv_it.at[it].set(conv)
-            xb2 = update_data(xb, cb, root_l, ml, n_valid=n_valid)
-            cb2 = update_cov(cb, root_l, ml)
-            ml2 = ml & (jnp.arange(m) != root_l)
+            with jax.named_scope("update"):
+                xb2 = update_data(xb, cb, root_l, ml, n_valid=n_valid)
+                cb2 = update_cov(cb, root_l, ml)
+                ml2 = ml & (jnp.arange(m) != root_l)
             return xb2, cb2, ml2, order, comps_it, rounds_it, conv_it
 
         st = (xb, cb, mloc, order, comps_it, rounds_it, conv_it)
-        xb, cb, mloc, order, comps_it, rounds_it, conv_it = jax.lax.fori_loop(
-            0, cnt, body, st
-        )
+        with jax.named_scope(f"order_{m}"):
+            xb, cb, mloc, order, comps_it, rounds_it, conv_it = (
+                jax.lax.fori_loop(0, cnt, body, st))
         pos += cnt
 
     # One live row remains (for a full buffer); no find-root needed (matches
@@ -673,10 +678,12 @@ def _result_from_counters(order, comps_it, rounds_it, conv_it, p: int,
     round counters (``utils.schedule.HOP_*``) — and its (pods, ring)
     ``topology``; they aggregate into ``ParaLiNGAMResult.wire`` and ride
     each ``per_iteration`` record as a ``hops`` tuple."""
-    comps_np = np.asarray(comps_it)
-    rounds_np = np.asarray(rounds_it)
-    conv_np = np.asarray(conv_it)
-    hops_np = None if hops_it is None else np.asarray(hops_it)
+    with tracing.span("repro.readback"):
+        order_np = np.asarray(order)
+        comps_np = np.asarray(comps_it)
+        rounds_np = np.asarray(rounds_it)
+        conv_np = np.asarray(conv_it)
+        hops_np = None if hops_it is None else np.asarray(hops_it)
     per_iter = [
         {
             "r": r,
@@ -718,7 +725,7 @@ def _result_from_counters(order, comps_it, rounds_it, conv_it, p: int,
         )
     comps_dense = sum(r * (r - 1) // 2 for r in range(2, p + 1))
     return ParaLiNGAMResult(
-        order=[int(v) for v in np.asarray(order)],
+        order=[int(v) for v in order_np],
         comparisons=int(comps_np.sum()),
         comparisons_dense=comps_dense,
         comparisons_serial=2 * comps_dense,
@@ -881,10 +888,12 @@ def _pipeline_impl(x, gamma0, gamma_growth, n_valid, mask0, *,
     permutation, exactly like the numpy oracle — see ``core.adjacency``)."""
     from repro.core.adjacency import adjacency_from_order, complete_order
 
-    xn = normalize(x, n_valid=n_valid)
-    if mask0 is not None:
-        xn = jnp.where(mask0[:, None], xn, 0.0)  # dead rows exactly zero
-    c = cov_matrix(xn, n_valid=n_valid)
+    with jax.named_scope("normalize"):
+        xn = normalize(x, n_valid=n_valid)
+        if mask0 is not None:
+            xn = jnp.where(mask0[:, None], xn, 0.0)  # dead rows exactly zero
+    with jax.named_scope("covariance"):
+        c = cov_matrix(xn, n_valid=n_valid)
     order, comps_it, rounds_it, conv_it = _scan_order_impl(
         xn, c, gamma0, gamma_growth, block_j=block_j, backend=backend,
         min_bucket=min_bucket, threshold=threshold, chunk=chunk,
@@ -892,10 +901,11 @@ def _pipeline_impl(x, gamma0, gamma_growth, n_valid, mask0, *,
     )
     if not adjacency:
         return order, comps_it, rounds_it, conv_it
-    perm = order if mask0 is None else complete_order(order, mask0)
-    b, omega = adjacency_from_order(
-        x, perm, mask=mask0, n_valid=n_valid, prune_below=prune_below
-    )
+    with jax.named_scope("adjacency"):
+        perm = order if mask0 is None else complete_order(order, mask0)
+        b, omega = adjacency_from_order(
+            x, perm, mask=mask0, n_valid=n_valid, prune_below=prune_below
+        )
     return order, comps_it, rounds_it, conv_it, b, omega
 
 
@@ -920,8 +930,9 @@ def _pipeline_fn(batched: bool, rules, **static):
     return jax.jit(run)
 
 
-# Host-side estimator dispatch counters, threaded up into the serving stats
-# surface (``serve.async_engine.AsyncLingamEngine.stats``).
+# Host-side estimator dispatch counters, kept in ``utils.tracing``'s counter
+# store and threaded up into the serving stats surface
+# (``serve.async_engine.AsyncLingamEngine.stats``).
 #
 #   "kernel_bypass"  — dispatches where a kernel backend was requested but a
 #     jnp formulation ran instead. Since the moments redesign every backend
@@ -934,32 +945,42 @@ def _pipeline_fn(batched: bool, rules, **static):
 #     in ``AsyncLingamEngine.stats()`` so a deployment can tell "kernels
 #     were never requested" from "kernels silently unavailable". Replaces
 #     the old warn-once RuntimeWarning.
-dispatch_stats: dict = {"kernel_bypass": 0, "auto_downgrade": 0}
-# N submitter + dispatcher-replica threads all funnel through _bump_stat;
-# the += races without this (lost increments under the GIL's bytecode-level
-# interleaving).
-_dispatch_stats_mu = threading.Lock()
+DISPATCH_COUNTERS = ("kernel_bypass", "auto_downgrade")
+
+
+class _DispatchStats(Mapping):
+    """Read-only live view of the ``DISPATCH_COUNTERS``."""
+
+    def __getitem__(self, key: str) -> int:
+        if key not in DISPATCH_COUNTERS:
+            raise KeyError(key)
+        return tracing.counters(key)[key]
+
+    def __iter__(self):
+        return iter(DISPATCH_COUNTERS)
+
+    def __len__(self) -> int:
+        return len(DISPATCH_COUNTERS)
+
+
+dispatch_stats = _DispatchStats()
 
 
 def reset_dispatch_stats() -> None:
     """Zero ``dispatch_stats`` (tests). Thread-safe against concurrent
     dispatches."""
-    with _dispatch_stats_mu:
-        for k in dispatch_stats:
-            dispatch_stats[k] = 0
+    tracing.reset(*DISPATCH_COUNTERS)
 
 
 def dispatch_stats_snapshot() -> dict:
-    """Consistent point-in-time copy of ``dispatch_stats`` (the live dict
-    may be mid-update in another thread)."""
-    with _dispatch_stats_mu:
-        return dict(dispatch_stats)
+    """Consistent point-in-time copy of ``dispatch_stats`` (another thread
+    may be counting)."""
+    return tracing.counters(*DISPATCH_COUNTERS)
 
 
 def _bump_stat(key: str, delta: int = 1) -> None:
     """Thread-safe ``dispatch_stats`` increment."""
-    with _dispatch_stats_mu:
-        dispatch_stats[key] += delta
+    tracing.count(key, delta)
 
 
 def _note_backend(cfg: ParaLiNGAMConfig, backend: str) -> None:
@@ -969,6 +990,22 @@ def _note_backend(cfg: ParaLiNGAMConfig, backend: str) -> None:
     ``dispatch_stats``)."""
     if cfg.score_backend == "auto" and backend.startswith("xla"):
         _bump_stat("auto_downgrade")
+
+
+def _to_device(x, dtype):
+    """``x`` as a device array of ``dtype``. Host data is cast on the host
+    and then handed to the device, under the ``repro.handoff`` span with its
+    ``cast`` and ``put`` children, counting ``handoff_bytes``; a device
+    array converts on the device and records nothing."""
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, dtype)
+    with tracing.span("repro.handoff"):
+        with tracing.span("repro.handoff.cast"):
+            xh = np.asarray(x, dtype)
+        with tracing.span("repro.handoff.put"):
+            xd = jnp.asarray(xh)
+    tracing.count("handoff_bytes", xh.nbytes)
+    return xd
 
 
 def _pipeline_call(x, cfg: ParaLiNGAMConfig, *, adjacency: bool,
@@ -996,9 +1033,11 @@ def _pipeline_call(x, cfg: ParaLiNGAMConfig, *, adjacency: bool,
 
 
 def _run_pipeline(x, cfg: ParaLiNGAMConfig, **kw):
-    backend, fn, args = _pipeline_call(x, cfg, **kw)
-    _note_backend(cfg, backend)
-    return fn(*args)
+    x = _to_device(x, cfg.dtype)
+    with tracing.span("repro.dispatch"):
+        backend, fn, args = _pipeline_call(x, cfg, **kw)
+        _note_backend(cfg, backend)
+        return fn(*args)
 
 
 def lower_fit(x, config: ParaLiNGAMConfig | None = None,
@@ -1018,6 +1057,7 @@ def lower_fit(x, config: ParaLiNGAMConfig | None = None,
     return fn.lower(*args)
 
 
+@tracing.span("repro.fit")
 def fit(x, config: ParaLiNGAMConfig | None = None, prune_below: float = 0.0,
         *, validate: bool = False):
     """Full DirectLiNGAM pipeline: causal order (step 1) + causal strengths B
@@ -1047,21 +1087,21 @@ def fit(x, config: ParaLiNGAMConfig | None = None, prune_below: float = 0.0,
         from repro.core.adjacency import adjacency_from_order_jit
 
         result = causal_order(x, cfg)
-        b, omega = adjacency_from_order_jit(
-            jnp.asarray(x, cfg.dtype),
-            jnp.asarray(result.order, jnp.int32),
-            prune_below=prune_below,
+        xd = _to_device(x, cfg.dtype)
+        with tracing.span("repro.dispatch"):
+            b, omega = adjacency_from_order_jit(
+                xd, jnp.asarray(result.order, jnp.int32),
+                prune_below=prune_below,
+            )
+    else:
+        p = np.shape(x)[0]
+        order, comps_it, rounds_it, conv_it, b, omega = _run_pipeline(
+            x, cfg, adjacency=True, batched=False, prune_below=prune_below,
         )
+        result = _result_from_counters(order, comps_it, rounds_it, conv_it,
+                                       p, cfg.max_rounds, stacklevel=4)
+    with tracing.span("repro.readback"):
         result.noise_var = np.asarray(omega)
-        result.diagnostics = diag
-        return result, b
-    p = np.shape(x)[0]
-    order, comps_it, rounds_it, conv_it, b, omega = _run_pipeline(
-        x, cfg, adjacency=True, batched=False, prune_below=prune_below,
-    )
-    result = _result_from_counters(order, comps_it, rounds_it, conv_it, p,
-                                   cfg.max_rounds)
-    result.noise_var = np.asarray(omega)
     result.diagnostics = diag
     return result, b
 
@@ -1097,7 +1137,7 @@ def _coerce_batch(xs, cfg: ParaLiNGAMConfig, n_valid, mask, caller: str):
             "the batch axis via `rules` instead) or per-dataset fit() for "
             "the ring"
         )
-    xs = jnp.asarray(xs, cfg.dtype)
+    xs = _to_device(xs, cfg.dtype)
     if xs.ndim != 3:
         raise ValueError(f"{caller} wants (B, p, n), got {xs.shape}")
     nv = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
@@ -1107,6 +1147,7 @@ def _coerce_batch(xs, cfg: ParaLiNGAMConfig, n_valid, mask, caller: str):
     return xs, nv, mk
 
 
+@tracing.span("repro.fit_batch")
 def fit_batch(xs, config: ParaLiNGAMConfig | None = None, *, n_valid=None,
               mask=None, rules=None, prune_below: float = 0.0) -> BatchFitResult:
     """Batched one-dispatch DirectLiNGAM over ``xs: (B, p, n)`` — the same
@@ -1154,10 +1195,11 @@ class CompiledFitBatch:
     compiled: object  # jax.stages.Compiled
     compile_seconds: float  # what the pre-warm saved the first request
 
+    @tracing.span("repro.fit_batch")
     def __call__(self, xs, n_valid=None, mask=None) -> BatchFitResult:
         cfg = self.cfg
         _note_backend(cfg, self.backend)
-        xs = jnp.asarray(xs, cfg.dtype)
+        xs = _to_device(xs, cfg.dtype)
         if xs.shape != (self.batch, self.p, self.n):
             raise ValueError(
                 f"CompiledFitBatch is specialized to "
@@ -1171,13 +1213,14 @@ class CompiledFitBatch:
                 nv = jnp.broadcast_to(nv, (self.batch,))
             mk = (jnp.ones((self.batch, self.p), bool)
                   if mask is None else jnp.asarray(mask, bool))
-            out = self.compiled(xs, g0, gg, nv, mk)
         else:
             if n_valid is not None or mask is not None:
                 raise ValueError(
                     "this executable was compiled for exact (unpadded) "
                     "batches; aot_fit_batch(padded=True) for the seams")
-            out = self.compiled(xs, g0, gg, None, None)
+            nv = mk = None
+        with tracing.span("repro.dispatch"):
+            out = self.compiled(xs, g0, gg, nv, mk)
         order, comps, rounds, conv, b, omega = out
         return BatchFitResult(orders=order, comparisons=comps, rounds=rounds,
                               converged=conv, b=b, noise_var=omega)

@@ -58,6 +58,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.covariance import VAR_EPS, cov_matrix, normalize, rank1_gates
 from repro.dist.ring import _ring_body, _ring_threshold_body
+from repro.utils import tracing
 from repro.utils.schedule import make_schedule
 from repro.utils.shapes import next_pow2
 
@@ -121,84 +122,86 @@ def _make_ring_order_fn(mesh: Mesh, sample_axis: str | None, p: int, n: int,
              hops_it) = st
             mk_all = jax.lax.all_gather(mk, row_axes, tiled=True)
             # --- find root: messaging ring over the live blocks ---
-            if threshold:
-                scores, comps, rounds, conv, hops = _ring_threshold_body(
-                    x_loc, c_loc, mk, ring_axes=("ring",),
-                    ring_sizes=(big_r,), pod_axis="pod", pod_size=pods,
-                    sample_axis=sample_axis,
-                    gamma0=gamma0, gamma_growth=gamma_growth,
-                    chunk=chunk, max_rounds=max_rounds,
-                )
-            else:
-                scores, hop_tally = _ring_body(
-                    x_loc, c_loc, mk, ring_axes=("ring",),
-                    ring_sizes=(big_r,), pod_axis="pod", pod_size=pods,
-                    sample_axis=sample_axis, backend=backend,
-                )
-                hops = jnp.asarray(hop_tally, jnp.int32)
-                r = jnp.sum(mk_all).astype(cdtype)  # live rows this iteration
-                comps = r * (r - 1) // 2
-                rounds = jnp.asarray(0, jnp.int32)
-                conv = jnp.asarray(True)
-            s_all = jax.lax.all_gather(scores, row_axes, tiled=True)  # (m,)
-            root = jnp.argmin(s_all).astype(jnp.int32)  # stage-buffer index
+            with jax.named_scope("find_root"):
+                if threshold:
+                    scores, comps, rounds, conv, hops = _ring_threshold_body(
+                        x_loc, c_loc, mk, ring_axes=("ring",),
+                        ring_sizes=(big_r,), pod_axis="pod", pod_size=pods,
+                        sample_axis=sample_axis,
+                        gamma0=gamma0, gamma_growth=gamma_growth,
+                        chunk=chunk, max_rounds=max_rounds,
+                    )
+                else:
+                    scores, hop_tally = _ring_body(
+                        x_loc, c_loc, mk, ring_axes=("ring",),
+                        ring_sizes=(big_r,), pod_axis="pod", pod_size=pods,
+                        sample_axis=sample_axis, backend=backend,
+                    )
+                    hops = jnp.asarray(hop_tally, jnp.int32)
+                    r = jnp.sum(mk_all).astype(cdtype)  # live rows this iteration
+                    comps = r * (r - 1) // 2
+                    rounds = jnp.asarray(0, jnp.int32)
+                    conv = jnp.asarray(True)
+                s_all = jax.lax.all_gather(scores, row_axes, tiled=True)  # (m,)
+                root = jnp.argmin(s_all).astype(jnp.int32)  # stage-buffer index
             order = order.at[pos + k].set(ig_all[root])
             comps_it = comps_it.at[pos + k].set(comps)
             rounds_it = rounds_it.at[pos + k].set(rounds.astype(jnp.int32))
             conv_it = conv_it.at[pos + k].set(conv)
             hops_it = hops_it.at[pos + k].set(hops)
 
-            # --- broadcast the root's rows: the only per-iteration wire
-            # traffic besides the (m,) score/mask gathers. x_root is the
-            # *local sample shard* of the root row ((n/|model|,)), c_root its
-            # full correlation row ((m,)).
-            my = (jax.lax.axis_index("pod") * big_r
-                  + jax.lax.axis_index("ring"))
-            owns = (my == root // m_l)
-            r_l = root % m_l
-            x_root = jax.lax.psum(
-                jnp.where(
-                    owns, jax.lax.dynamic_index_in_dim(x_loc, r_l, 0, False),
-                    0.0,
-                ),
-                row_axes,
-            )
-            c_root = jax.lax.psum(
-                jnp.where(
-                    owns, jax.lax.dynamic_index_in_dim(c_loc, r_l, 0, False),
-                    0.0,
-                ),
-                row_axes,
-            )
+            with jax.named_scope("update"):
+                # --- broadcast the root's rows: the only per-iteration wire
+                # traffic besides the (m,) score/mask gathers. x_root is the
+                # *local sample shard* of the root row ((n/|model|,)), c_root its
+                # full correlation row ((m,)).
+                my = (jax.lax.axis_index("pod") * big_r
+                      + jax.lax.axis_index("ring"))
+                owns = (my == root // m_l)
+                r_l = root % m_l
+                x_root = jax.lax.psum(
+                    jnp.where(
+                        owns, jax.lax.dynamic_index_in_dim(x_loc, r_l, 0, False),
+                        0.0,
+                    ),
+                    row_axes,
+                )
+                c_root = jax.lax.psum(
+                    jnp.where(
+                        owns, jax.lax.dynamic_index_in_dim(c_loc, r_l, 0, False),
+                        0.0,
+                    ),
+                    row_axes,
+                )
 
-            # --- UpdateData (Alg. 7, Eq. 10) on own rows, in place.
-            # Matches covariance.update_data: dead + root rows pass through
-            # (b = 0, s = 1, scale = 1).
-            row_ids = my * m_l + jnp.arange(m_l, dtype=jnp.int32)
-            live = mk & (row_ids != root)
-            b_raw = jax.lax.dynamic_index_in_dim(c_loc, root, 1, False)  # (m_l,)
-            b, s_row = rank1_gates(b_raw, live)
-            out = (x_loc - b[:, None] * x_root[None, :]) / s_row[:, None]
-            sq = jnp.sum(jnp.square(out), axis=1)
-            if sample_axis is not None:
-                sq = jax.lax.psum(sq, sample_axis)
-            var = sq / max(n - 1, 1)
-            scale = jnp.where(live, jax.lax.rsqrt(jnp.maximum(var, VAR_EPS)), 1.0)
-            x2 = out * scale[:, None]
+                # --- UpdateData (Alg. 7, Eq. 10) on own rows, in place.
+                # Matches covariance.update_data: dead + root rows pass through
+                # (b = 0, s = 1, scale = 1).
+                row_ids = my * m_l + jnp.arange(m_l, dtype=jnp.int32)
+                live = mk & (row_ids != root)
+                b_raw = jax.lax.dynamic_index_in_dim(c_loc, root, 1, False)  # (m_l,)
+                b, s_row = rank1_gates(b_raw, live)
+                out = (x_loc - b[:, None] * x_root[None, :]) / s_row[:, None]
+                sq = jnp.sum(jnp.square(out), axis=1)
+                if sample_axis is not None:
+                    sq = jax.lax.psum(sq, sample_axis)
+                var = sq / max(n - 1, 1)
+                scale = jnp.where(live, jax.lax.rsqrt(jnp.maximum(var, VAR_EPS)), 1.0)
+                x2 = out * scale[:, None]
 
-            # --- UpdateCovMat (Alg. 8, Eq. 11) on own rows x all columns.
-            # b over columns comes from the broadcast root row (c is exactly
-            # symmetric), gated by the *global* live mask so dead columns
-            # pass through — same contract as covariance.update_cov.
-            col_ids = jnp.arange(m, dtype=jnp.int32)
-            col_live = mk_all & (col_ids != root)
-            b_col, s_col = rank1_gates(c_root, col_live)
-            c2 = jnp.clip(
-                (c_loc - b[:, None] * b_col[None, :])
-                / (s_row[:, None] * s_col[None, :]),
-                -1.0, 1.0,
-            )
-            c2 = jnp.where(row_ids[:, None] == col_ids[None, :], 1.0, c2)
+                # --- UpdateCovMat (Alg. 8, Eq. 11) on own rows x all columns.
+                # b over columns comes from the broadcast root row (c is exactly
+                # symmetric), gated by the *global* live mask so dead columns
+                # pass through — same contract as covariance.update_cov.
+                col_ids = jnp.arange(m, dtype=jnp.int32)
+                col_live = mk_all & (col_ids != root)
+                b_col, s_col = rank1_gates(c_root, col_live)
+                c2 = jnp.clip(
+                    (c_loc - b[:, None] * b_col[None, :])
+                    / (s_row[:, None] * s_col[None, :]),
+                    -1.0, 1.0,
+                )
+                c2 = jnp.where(row_ids[:, None] == col_ids[None, :], 1.0, c2)
 
             # --- retire the root: re-mask, don't re-shard.
             mk2 = mk & (row_ids != root)
@@ -253,18 +256,20 @@ def _make_ring_order_fn(mesh: Mesh, sample_axis: str | None, p: int, n: int,
                 # Compaction (or initial pad-to-pow2): the only point rows
                 # move between shards — <= log2 p times per recovery, vs the
                 # host driver's re-gather every iteration.
-                live = p - pos  # static: one root retires per iteration
-                sel = jnp.nonzero(mloc, size=m, fill_value=0)[0].astype(jnp.int32)
-                idx_g = idx_g[sel]
-                xb = xb[sel]
-                cb = cb[sel][:, sel]
-                mloc = jnp.arange(m) < live
+                with jax.named_scope("compact"):
+                    live = p - pos  # static: one root retires per iteration
+                    sel = jnp.nonzero(mloc, size=m, fill_value=0)[0].astype(jnp.int32)
+                    idx_g = idx_g[sel]
+                    xb = xb[sel]
+                    cb = cb[sel][:, sel]
+                    mloc = jnp.arange(m) < live
                 m_cur = m
-            (xb, cb, mloc, idx_g, order, comps_it, rounds_it, conv_it,
-             hops_it) = stage(
-                xb, cb, mloc, idx_g, order, comps_it, rounds_it, conv_it,
-                hops_it
-            )
+            with jax.named_scope(f"order_{m}"):
+                (xb, cb, mloc, idx_g, order, comps_it, rounds_it, conv_it,
+                 hops_it) = stage(
+                    xb, cb, mloc, idx_g, order, comps_it, rounds_it, conv_it,
+                    hops_it
+                )
             pos += cnt
         # One live row remains; no find-root needed (matches the host driver).
         order = order.at[p - 1].set(idx_g[jnp.argmax(mloc)])
@@ -343,11 +348,12 @@ def causal_order_ring(x, config=None, mesh=None):
         ConfigError,
         ParaLiNGAMConfig,
         _result_from_counters,
+        _to_device,
         causal_order_scan,
     )
 
     cfg = config or ParaLiNGAMConfig()
-    x = jnp.asarray(x, cfg.dtype)
+    x = _to_device(x, cfg.dtype)
     p, n = x.shape
     want_pods = cfg.ring_topology[0] if cfg.ring_topology else None
     try:
@@ -365,19 +371,21 @@ def causal_order_ring(x, config=None, mesh=None):
 
     from repro.kernels import ops as kops
 
-    backend = kops.select_backend(cfg)
-    xn = normalize(x)
-    c = cov_matrix(xn)
-    run = _make_ring_order_fn(
-        canon, sample_axis, p, n, next_pow2(max(cfg.min_bucket, 1)),
-        backend=backend, threshold=cfg.threshold, chunk=cfg.chunk,
-        gamma0=float(cfg.gamma0), gamma_growth=float(cfg.gamma_growth),
-        max_rounds=cfg.max_rounds,
-    )
-    # shard_map wants the context mesh to be the one it maps over, so the
-    # canonical mesh stands in for the caller's ``jax.set_mesh`` mesh here.
-    with jax.set_mesh(canon):
-        order, comps_it, rounds_it, conv_it, hops_it = run(xn, c)
+    with tracing.span("repro.ring.prepare"):
+        xn = normalize(x)
+        c = cov_matrix(xn)
+    with tracing.span("repro.dispatch"):
+        run = _make_ring_order_fn(
+            canon, sample_axis, p, n, next_pow2(max(cfg.min_bucket, 1)),
+            backend=kops.select_backend(cfg), threshold=cfg.threshold,
+            chunk=cfg.chunk, gamma0=float(cfg.gamma0),
+            gamma_growth=float(cfg.gamma_growth), max_rounds=cfg.max_rounds,
+        )
+        # shard_map wants the context mesh to be the one it maps over, so
+        # the canonical mesh stands in for the caller's ``jax.set_mesh``
+        # mesh here.
+        with jax.set_mesh(canon):
+            order, comps_it, rounds_it, conv_it, hops_it = run(xn, c)
     return _result_from_counters(order, comps_it, rounds_it, conv_it, p,
                                  cfg.max_rounds, hops_it=hops_it,
                                  topology=(pods, big_r))

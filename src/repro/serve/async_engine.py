@@ -54,7 +54,7 @@ import threading
 
 import numpy as np
 
-from repro.core.paralingam import aot_fit_batch, dispatch_stats_snapshot
+from repro.core.paralingam import DISPATCH_COUNTERS, aot_fit_batch
 from repro.core.paralingam import ParaLiNGAMConfig
 from repro.serve.batching import (
     BatchingConfig,
@@ -71,6 +71,7 @@ from repro.serve.lingam_engine import (
     dispatch_bucket,
 )
 from repro.serve.replica import ReplicaPool, ReplicaPoolConfig
+from repro.utils import tracing
 from repro.utils.shapes import next_pow2
 
 
@@ -271,11 +272,14 @@ class AsyncLingamEngine:
         route, so it must read 0 (asserted by the engine suites).
         ``auto_downgrade`` counts dispatches where ``score_backend="auto"``
         resolved to a jnp formulation — the off-accelerator platform policy
-        report that replaced the old bypass RuntimeWarning."""
+        report that replaced the old bypass RuntimeWarning.
+        ``compiles``, ``compile_cache_hits`` and ``handoff_bytes`` are the
+        process-wide ``utils.tracing`` counters: executables built or loaded
+        from the compile cache, the loads alone, and bytes of host data
+        handed to the device."""
         out = self.core.snapshot()
-        est = dispatch_stats_snapshot()
-        out["kernel_bypass"] = est["kernel_bypass"]
-        out["auto_downgrade"] = est["auto_downgrade"]
+        out.update(tracing.counters(*DISPATCH_COUNTERS, "compiles",
+                                    "compile_cache_hits", "handoff_bytes"))
         with self._inv_mu:
             out["invalid_datasets"] = self._invalid
         out["prewarm"] = dict(self.prewarm_stats)
