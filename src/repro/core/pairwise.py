@@ -265,24 +265,31 @@ def residual_entropy_matrix(xn, c, block_j: int = 32, unroll: bool = False,
                             n_valid=None):
     """Full HR: (p, p), computed in j-blocks to bound the (p, bj, n) buffer.
 
+    A p that ``block_j`` does not divide gets zero columns up to the next
+    multiple (a zero column with zero correlation is a finite residual); their
+    entropies are sliced off, so the buffer stays (p, block_j, n) whatever p.
     ``unroll=True`` replaces the lax.map with a python loop — used by the
     dry-run cost extraction (XLA counts loop bodies once)."""
     p = xn.shape[0]
-    if p % block_j != 0:
-        block_j = p  # fall back to one block for awkward sizes
-    nb = p // block_j
+    block_j = min(block_j, p)
+    p_pad = p + (-p) % block_j
+    nb = p_pad // block_j
+    xs, cs = xn, c
+    if p_pad != p:
+        xs = jnp.pad(xn, ((0, p_pad - p), (0, 0)))
+        cs = jnp.pad(c, ((0, 0), (0, p_pad - p)))
 
     def one_block(jb):
         cols = jb * block_j + jnp.arange(block_j)
-        xj = xn[cols]
-        c_cols = c[:, cols]
+        xj = xs[cols]
+        c_cols = cs[:, cols]
         return residual_entropy_block(xn, c_cols, xj, n_valid=n_valid)
 
     if unroll:
         blocks = jnp.stack([one_block(jnp.int32(i)) for i in range(nb)])
     else:
         blocks = jax.lax.map(one_block, jnp.arange(nb))  # (nb, p, bj)
-    return jnp.transpose(blocks, (1, 0, 2)).reshape(p, p)
+    return jnp.transpose(blocks, (1, 0, 2)).reshape(p, p_pad)[:, :p]
 
 
 def pair_stat_matrix(hx, hr):

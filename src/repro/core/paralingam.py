@@ -39,7 +39,8 @@ and both S[i] += min(0, I)^2 and S[j] += min(0, -I)^2 are applied
 (Section 3.1).
 
 Across outer iterations, the remaining set U shrinks; rows are compacted into
-power-of-two *buckets* so each bucket size compiles once (<= log2 p
+*buckets* (powers of two, except the device drivers' top stage, which is p
+rounded up to the bucket floor) so each bucket size compiles once (<= log2 p
 specializations) and the total search work is sum_r r^2 n, matching the
 paper's per-iteration shrinking workers.
 
@@ -76,7 +77,7 @@ from repro.core.pairwise import (
 )
 from repro.core.pairwise import residual_entropy_matrix as _hr_jnp
 from repro.utils import tracing
-from repro.utils.schedule import make_schedule
+from repro.utils.schedule import make_schedule, whole_chunk
 from repro.utils.shapes import next_pow2
 
 
@@ -406,10 +407,8 @@ def _find_root_threshold_impl(
     # ``kernels.ops.pair_moments`` is the seam to add one later).
     # Round the chunk down to a divisor of m (m is static at trace time) so
     # non-power-of-two row counts (bucket=False with awkward p) still reshape
-    # into whole chunks; worst case chunk=1 == the paper's one-at-a-time worker.
-    chunk = max(1, min(chunk, m))
-    while m % chunk:
-        chunk -= 1
+    # into whole chunks.
+    chunk = whole_chunk(chunk, m)
     nc = m // chunk
     idx = jnp.arange(m)
     pair_valid = mask[:, None] & mask[None, :] & ~jnp.eye(m, dtype=bool)
@@ -534,13 +533,14 @@ def _update_iteration(xn, c, root, mask, n_valid=None):
     return xn2, c2, mask2
 
 
-def _scan_stages(p: int, min_bucket: int) -> list[tuple[int, int]]:
+def _scan_stages(p: int, min_bucket: int,
+                 chunk: int = 1) -> list[tuple[int, int]]:
     """Static stage plan: (buffer size m, iteration count) pairs for the
-    single-shard scan driver — now just the R=1 slice of the unified
-    topology-aware :func:`repro.utils.schedule.make_schedule` (the ring
-    driver consumes the same object with its ring size, so the two plans
-    cannot drift)."""
-    return list(make_schedule(p, min_bucket).stages)
+    single-shard scan driver — the R=1 slice of the unified topology-aware
+    :func:`repro.utils.schedule.make_schedule` (the ring driver consumes the
+    same object with its ring size, so the two plans cannot drift).
+    ``chunk`` is the threshold machine's, 1 for dense sweeps."""
+    return list(make_schedule(p, min_bucket, chunk=chunk).stages)
 
 
 def _scan_order_impl(xn, c, gamma0, gamma_growth, block_j: int = 32,
@@ -551,14 +551,19 @@ def _scan_order_impl(xn, c, gamma0, gamma_growth, block_j: int = 32,
     """Device-resident outer loop: all p find-root -> update iterations in
     ONE dispatch, with no host round-trips.
 
-    The loop is staged on the same power-of-two schedule as the host driver's
-    buckets, but entirely on-device: each stage is a ``lax.fori_loop`` over
+    The loop is staged on :func:`repro.utils.schedule.make_schedule`'s
+    plan, entirely on-device: each stage is a ``lax.fori_loop`` over
     fixed-size mask-based buffers, and the <= log2(p) stage transitions
     compact live rows with a device-side ``jnp.nonzero(size=m)`` gather (the
     host driver instead syncs ``int(root)`` and re-gathers from numpy every
-    one of the p iterations). Work profile and per-iteration float ops match
-    the bucketed host driver exactly — padded rows are masked out of every
-    reduction — so the returned order is identical.
+    one of the p iterations). The buckets are the host driver's powers of
+    two except the top stage, which is p rounded up to the bucket floor
+    (and, with ``threshold=True``, to whole chunks) rather than
+    ``next_pow2(p)``, so the scan carries fewer dead rows there.
+    Padded rows are masked out of every reduction, so the order is the host
+    driver's up to float32 rounding: an op over a buffer of another size
+    may round a live value differently in the last bit, which can swap
+    near-tied roots.
 
     ``threshold=True`` replaces the dense evaluation with the threshold
     state machine (``_find_root_threshold_impl``'s ``lax.while_loop`` over
@@ -598,7 +603,7 @@ def _scan_order_impl(xn, c, gamma0, gamma_growth, block_j: int = 32,
     mloc = jnp.ones((p,), bool) if mask0 is None else mask0
     m_cur = p
     pos = 0
-    for m, cnt in _scan_stages(p, min_bucket):
+    for m, cnt in _scan_stages(p, min_bucket, chunk if threshold else 1):
         if m != m_cur:
             # Compaction: pack live rows first; the live count is derived on
             # device (== the static p - pos when mask0 is None, fewer when a
@@ -1032,6 +1037,16 @@ def _pipeline_call(x, cfg: ParaLiNGAMConfig, *, adjacency: bool,
     return backend, fn, args
 
 
+def _count_pair_slots(p: int, cfg: ParaLiNGAMConfig, datasets: int = 1):
+    """Count ``score_pair_slots`` for a dense fit: the buffer pairs its stage
+    plan sweeps (``Schedule.pair_slots``), once per data set. ``1 -
+    comparisons_dense / score_pair_slots`` is the fit's padded share. A
+    threshold fit sweeps only its pending pairs and counts nothing."""
+    if not cfg.threshold:
+        tracing.count("score_pair_slots",
+                      datasets * make_schedule(p, cfg.min_bucket).pair_slots)
+
+
 def _run_pipeline(x, cfg: ParaLiNGAMConfig, **kw):
     x = _to_device(x, cfg.dtype)
     with tracing.span("repro.dispatch"):
@@ -1100,6 +1115,7 @@ def fit(x, config: ParaLiNGAMConfig | None = None, prune_below: float = 0.0,
         )
         result = _result_from_counters(order, comps_it, rounds_it, conv_it,
                                        p, cfg.max_rounds, stacklevel=4)
+        _count_pair_slots(p, cfg)
     with tracing.span("repro.readback"):
         result.noise_var = np.asarray(omega)
     result.diagnostics = diag
@@ -1168,6 +1184,7 @@ def fit_batch(xs, config: ParaLiNGAMConfig | None = None, *, n_valid=None,
         xs, cfg, adjacency=True, batched=True, n_valid=nv, mask0=mk,
         rules=rules, prune_below=prune_below,
     )
+    _count_pair_slots(xs.shape[1], cfg, datasets=xs.shape[0])
     return BatchFitResult(orders=order, comparisons=comps, rounds=rounds,
                           converged=conv, b=b, noise_var=omega)
 
@@ -1221,6 +1238,7 @@ class CompiledFitBatch:
             nv = mk = None
         with tracing.span("repro.dispatch"):
             out = self.compiled(xs, g0, gg, nv, mk)
+        _count_pair_slots(self.p, cfg, datasets=self.batch)
         order, comps, rounds, conv, b, omega = out
         return BatchFitResult(orders=order, comparisons=comps, rounds=rounds,
                               converged=conv, b=b, noise_var=omega)

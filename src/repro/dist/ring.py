@@ -66,6 +66,7 @@ from repro.utils.schedule import (
     HOP_INTRA_OVL,
     HOP_INTRA_SEQ,
     make_hier_plan,
+    whole_chunk,
 )
 
 
@@ -362,11 +363,9 @@ def _ring_threshold_body(x_loc, c_loc, mask_loc, *, ring_axes: tuple,
     has_pairs = jnp.sum(mask_all) >= 2
 
     # Chunk rounded to a divisor of the block width so the visiting columns
-    # reshape into whole chunks (worst case 1 == the paper's one-at-a-time
-    # worker); the host machine applies the same rounding to its row count.
-    b = max(1, min(chunk, m_l))
-    while m_l % b:
-        b -= 1
+    # reshape into whole chunks; the host machine applies the same rounding
+    # to its row count.
+    b = whole_chunk(chunk, m_l)
     nc = m_l // b
     rows = jnp.broadcast_to(jnp.arange(m_l)[:, None], (m_l, b))
 

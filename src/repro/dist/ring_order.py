@@ -31,8 +31,8 @@ machine run *per shard* (``_ring_threshold_body``: pending chunks processed
 per hop for resident AND visiting rows, credits/done-masks riding the
 packet, gamma growth and termination psum'd ring-wide; see dist/ring.py).
 
-The outer loop consumes the topology-aware power-of-two bucket plan shared
-with the scan driver (``repro.utils.schedule.make_schedule`` with
+The outer loop consumes the topology-aware bucket plan shared with the scan
+driver (``repro.utils.schedule.make_schedule`` with
 ``ring=R``): block sizes stay static within a stage, so the ring schedule
 compiles once per stage (<= log2 p specializations), and the <= log2 p
 stage transitions compact live rows with a device-side
@@ -68,17 +68,20 @@ from repro.utils.shapes import next_pow2
 # ---------------------------------------------------------------------------
 
 
-def ring_order_stages(p: int, min_bucket: int, r: int) -> list[tuple[int, int]]:
+def ring_order_stages(p: int, min_bucket: int, r: int,
+                      chunk: int = 1) -> list[tuple[int, int]]:
     """Static stage plan ``[(buffer size m, iteration count), ...]``.
 
-    Now just the topology-aware :func:`repro.utils.schedule.make_schedule`
-    with ring size ``r``: each stage's m is pow-2, a multiple of ``r`` (so
-    the m/r-row blocks stay non-empty and equal, hence divisible), and >=
-    the live-row count of every iteration it covers. Total iterations sum
-    to p - 1 (the last live row needs no find-root). With r=1 this IS the
-    scan schedule (``core.paralingam._scan_stages``) — the two drivers
-    consume the same ``Schedule`` object and cannot drift."""
-    return list(make_schedule(p, min_bucket, ring=r).stages)
+    The topology-aware :func:`repro.utils.schedule.make_schedule` with ring
+    size ``r``: each stage's m is a multiple of ``r`` (so the m/r-row blocks
+    stay non-empty and equal), a power of two after the top stage (which is
+    p rounded up to the bucket floor and, for the threshold machine's
+    ``chunk``, to whole chunks per shard), and >= the live-row count of
+    every iteration it covers. Total iterations sum to p - 1 (the last live
+    row needs no find-root). With r=1 this IS the scan schedule
+    (``core.paralingam._scan_stages``) — the two drivers consume the same
+    ``Schedule`` object and cannot drift."""
+    return list(make_schedule(p, min_bucket, ring=r, chunk=chunk).stages)
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +113,8 @@ def _make_ring_order_fn(mesh: Mesh, sample_axis: str | None, p: int, n: int,
     shards = pods * big_r
     row_axes = ("pod", "ring")
     sched = make_schedule(p, min_bucket, ring=big_r, pods=pods,
-                          sample_shards=int(dict(mesh.shape).get("model", 1)))
+                          sample_shards=int(dict(mesh.shape).get("model", 1)),
+                          chunk=chunk if threshold else 1)
     stages = list(sched.stages)
     cdtype = jnp.int32
 
@@ -253,9 +257,9 @@ def _make_ring_order_fn(mesh: Mesh, sample_axis: str | None, p: int, n: int,
         pos = 0
         for m, cnt, stage in stage_fns:
             if m != m_cur:
-                # Compaction (or initial pad-to-pow2): the only point rows
-                # move between shards — <= log2 p times per recovery, vs the
-                # host driver's re-gather every iteration.
+                # Compaction (or the initial pad to the top stage): the only
+                # point rows move between shards — <= log2 p times per
+                # recovery, vs the host driver's re-gather every iteration.
                 with jax.named_scope("compact"):
                     live = p - pos  # static: one root retires per iteration
                     sel = jnp.nonzero(mloc, size=m, fill_value=0)[0].astype(jnp.int32)

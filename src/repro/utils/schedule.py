@@ -17,13 +17,18 @@ R, sample shards M), and emits the stage plan every driver consumes.
 Invariants (enforced at construction, property-tested in
 tests/test_schedule.py):
 
-  * every stage size m is a power of two and a multiple of ``pods * ring``
-    (the total shard count — every shard keeps an equal non-empty block);
+  * every stage size m is a multiple of ``pods * ring`` (the total shard
+    count — every shard keeps an equal non-empty block), and every stage
+    after the top one is a power of two; the top stage is p rounded up to
+    the bucket floor (and, for the threshold machine, to whole chunks per
+    shard), so a p that is not a power of two does not carry up to p dead
+    rows through its first stage;
   * stage m covers every iteration it spans: m >= live-row count r for each
     of its iterations (coverage — no compaction ever drops a live row);
   * iteration counts sum to p - 1 (the last live row needs no find-root);
-  * ``ring=1`` reproduces the scan driver's plan exactly (scan == ring at
-    R=1), so the two drivers cannot diverge;
+  * ``ring=1`` is the scan driver's plan (scan == ring at R=1), so the two
+    drivers cannot diverge. The host driver keeps plain ``next_pow2``
+    buckets, so its top buffer can be larger than the scan's;
   * the plan depends only on ``pods * ring``, so every (P, R) split of the
     same shard count compacts at the same iterations — hierarchical and
     flat rings of equal width recover bit-identical orders.
@@ -40,6 +45,7 @@ are asserted against.
 from __future__ import annotations
 
 import itertools
+import math
 from dataclasses import dataclass, field
 
 from repro.utils.shapes import next_pow2
@@ -64,6 +70,8 @@ class Schedule:
     #   the samples axis never compacts, but the (P, R, M) triple identifies
     #   the topology a plan was built for, and the analytic HBM/wire model in
     #   EXPERIMENTS.md reads all three factors off the schedule)
+    chunk: int = 1  # threshold machine's comparison chunk per worker (1 for
+    #   dense sweeps); the top stage gives every shard whole chunks
     stages: tuple[tuple[int, int], ...] = field(default=())
 
     def __post_init__(self):
@@ -73,11 +81,18 @@ class Schedule:
             raise ValueError(f"pod count must be a power of two, got {self.pods}")
         if self.sample_shards < 1:
             raise ValueError(f"sample_shards must be >= 1, got {self.sample_shards}")
+        if self.chunk < 1:
+            raise ValueError(f"chunk must be >= 1, got {self.chunk}")
         # Coverage + divisibility invariants: cheap, and they turn schedule
         # bugs into construction-time errors instead of silent wrong orders.
+        floor = bucket_floor(self.min_bucket, self.pods * self.ring)
         r = self.p
-        for m, cnt in self.stages:
-            if m & (m - 1):
+        for k, (m, cnt) in enumerate(self.stages):
+            if k == 0 and m & (m - 1) and m % floor:
+                raise ValueError(
+                    f"top stage size {m} is neither a power of two nor a "
+                    f"multiple of the bucket floor {floor}")
+            if k > 0 and m & (m - 1):
                 raise ValueError(f"stage size {m} is not a power of two")
             if m % (self.pods * self.ring):
                 raise ValueError(
@@ -107,6 +122,14 @@ class Schedule:
         """Stage transitions where rows move (bounded by log2 p)."""
         return max(len(self.stages) - 1, 0)
 
+    @property
+    def pair_slots(self) -> int:
+        """Unordered row pairs of the stage buffers, summed over the
+        iterations: ``sum(count * m(m-1)/2)``, what a dense sweep of the
+        plan visits, padding included. The live pairs of a full data set
+        are ``C(p+1, 3)``; the rest are dead rows."""
+        return sum(cnt * m * (m - 1) // 2 for m, cnt in self.stages)
+
     def block(self, m: int) -> int:
         """Per-shard row-block size at stage buffer size ``m``."""
         return m // (self.pods * self.ring)
@@ -126,19 +149,41 @@ class Schedule:
         return self.p - pos
 
 
-def make_schedule(p: int, min_bucket: int, ring: int = 1, pods: int = 1,
-                  sample_shards: int = 1) -> Schedule:
-    """Build the power-of-two bucket schedule for one recovery.
+def bucket_floor(min_bucket: int, shards: int = 1) -> int:
+    """Smallest stage buffer of a plan: ``next_pow2(max(min_bucket,
+    shards))``. The shard floor keeps every shard's block non-empty."""
+    return next_pow2(max(min_bucket, shards, 1))
 
-    The plan mirrors the host driver's bucketing: iteration at r live rows
-    runs in a buffer of size ``next_pow2(r)``, floored at
-    ``next_pow2(max(min_bucket, pods * ring))`` (the shard floor keeps every
-    shard's block non-empty) and capped at ``next_pow2(p)``. Consecutive
-    equal sizes merge into stages. A ring wider than the padded problem
-    degenerates to a single stage of size ``pods * ring`` — one row (or
-    less) per shard, the excess dead from the start. ``ring=1`` is exactly
-    the scan plan, and the stages depend only on the product ``pods * ring``
-    — every (P, R) factorization of one shard count shares one plan."""
+
+def whole_chunk(chunk: int, rows: int) -> int:
+    """The threshold machine's chunk over a block of ``rows`` visiting
+    columns: ``chunk`` rounded down to a divisor of ``rows``, so the block
+    reshapes into whole chunks (worst case 1, the paper's one-at-a-time
+    worker)."""
+    b = max(1, min(chunk, rows))
+    while rows % b:
+        b -= 1
+    return b
+
+
+def make_schedule(p: int, min_bucket: int, ring: int = 1, pods: int = 1,
+                  sample_shards: int = 1, chunk: int = 1) -> Schedule:
+    """Build the bucket schedule for one recovery.
+
+    An iteration at r live rows runs in a buffer of size ``next_pow2(r)``,
+    floored at ``floor = bucket_floor(min_bucket, pods * ring)`` and capped
+    at ``min(next_pow2(p), round_up(p, lcm(floor, chunk * pods * ring)))``;
+    consecutive equal sizes merge into stages. So the top stage is ``p``
+    rounded up to a multiple of the floor (itself a multiple of ``pods *
+    ring``: both are powers of two and the floor is the larger) and, for the
+    threshold machine's ``chunk``, of whole chunks per shard, so
+    :func:`whole_chunk` keeps the full chunk there; every later stage is a
+    power of two. Dense sweeps pass ``chunk=1``. A ring wider than the
+    padded problem degenerates to a single stage of size ``pods * ring`` —
+    one row (or less) per shard, the excess dead from the start. ``ring=1``
+    is the scan plan, and the stages depend only on the product
+    ``pods * ring`` — every (P, R) factorization of one shard count shares
+    one plan."""
     if ring < 1 or ring & (ring - 1):
         raise ValueError(f"ring size must be a power of two, got {ring}")
     if pods < 1 or pods & (pods - 1):
@@ -149,12 +194,16 @@ def make_schedule(p: int, min_bucket: int, ring: int = 1, pods: int = 1,
     elif shards > next_pow2(p):
         stages = ((shards, p - 1),)
     else:
-        cap = next_pow2(p)
-        floor = next_pow2(max(min_bucket, shards, 1))
+        floor = bucket_floor(min_bucket, shards)
+        # The top stage starts with all p rows live, so what its buffer
+        # holds above p is dead for the whole stage; every later stage
+        # starts full.
+        grain = math.lcm(floor, chunk * shards)
+        cap = min(next_pow2(p), -(-p // grain) * grain)
         ms = [min(cap, max(floor, next_pow2(r))) for r in range(p, 1, -1)]
         stages = tuple((m, len(list(g))) for m, g in itertools.groupby(ms))
     return Schedule(p=p, min_bucket=min_bucket, ring=ring, pods=pods,
-                    sample_shards=sample_shards, stages=stages)
+                    sample_shards=sample_shards, chunk=chunk, stages=stages)
 
 
 # ---------------------------------------------------------------------------

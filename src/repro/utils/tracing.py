@@ -26,6 +26,9 @@ Span and counter names the program uses:
   * ``repro.ring.prepare`` — the ring's eager normalize and covariance;
   * ``repro.readback`` — the host waiting on results it converts;
   * ``repro.compile`` and the counters ``compiles``, ``compile_cache_hits``;
+  * the counter ``score_pair_slots`` — the row pairs of the stage buffers a
+    dense ``fit``/``fit_batch`` sweeps, once per data set
+    (``utils.schedule.Schedule.pair_slots``); threshold fits add nothing;
   * the counters ``kernel_bypass`` and ``auto_downgrade``
     (``core.paralingam.dispatch_stats``).
 """

@@ -4,8 +4,9 @@ ring order drivers — and of the two-level ``("pod", "ring")`` hop plan
 (``make_hier_plan``) the hierarchical messaging ring executes.
 
 Invariants checked over a grid of (p, min_bucket, ring[, pods]) shapes:
-coverage (every stage buffer holds all its live rows), power-of-two and
-pod*ring-divisibility of every stage size, iteration counts summing to
+coverage (every stage buffer holds all its live rows), the stage-size law
+(the top stage p rounded up to the bucket floor, never above next_pow2(p);
+every later stage a power of two; every stage a multiple of pod*ring), iteration counts summing to
 p - 1, scan == ring at R = 1, plan invariance across (P, R) factorizations
 of one shard count, and the degenerate wide-ring plan. For the hop plan:
 exactly-once coverage of every unordered block pair across both levels,
@@ -17,6 +18,7 @@ construction-time ``ValueError``s, never silent wrong orders.
 """
 
 import itertools
+import math
 
 import pytest
 
@@ -28,10 +30,28 @@ from repro.utils.schedule import (
     Schedule,
     make_hier_plan,
     make_schedule,
+    whole_chunk,
 )
 from repro.utils.shapes import next_pow2
 
 PS = (2, 3, 5, 8, 16, 17, 31, 64, 85, 100, 129)
+
+
+def _assert_stage_sizes(sched, p, min_bucket, shards):
+    """The stage-size law: the top stage is ``min(next_pow2(p),
+    round_up(p, floor))`` with ``floor = next_pow2(max(min_bucket,
+    shards))`` (a ring wider than the padded problem takes one stage of
+    ``shards`` rows), every later stage a power of two, every stage a
+    multiple of the shard count."""
+    floor = next_pow2(max(min_bucket, shards, 1))
+    top = (shards if shards > next_pow2(p)
+           else min(next_pow2(p), -(-p // floor) * floor))
+    sizes = [m for m, _ in sched.stages]
+    assert sizes[0] == top, f"top stage {sizes[0]}, want {top}"
+    for m in sizes[1:]:
+        assert m & (m - 1) == 0, "stages below the top are powers of two"
+    for m in sizes:
+        assert m % shards == 0, "stage size must divide evenly over the ring"
 MIN_BUCKETS = (1, 4, 8, 32)
 RINGS = (1, 2, 4, 8)
 PODS = (1, 2, 4, 8)
@@ -43,10 +63,9 @@ PODS = (1, 2, 4, 8)
 def test_schedule_invariants(p, min_bucket, ring):
     sched = make_schedule(p, min_bucket, ring=ring)
     assert sched.total_iterations == p - 1
+    _assert_stage_sizes(sched, p, min_bucket, ring)
     r = p
     for m, cnt, pos in sched.walk():
-        assert m & (m - 1) == 0, "stage size must be a power of two"
-        assert m % ring == 0, "stage size must divide evenly over the ring"
         assert sched.block(m) * ring == m
         assert sched.block(m) >= 1
         assert sched.live_at(pos) == r
@@ -65,14 +84,92 @@ def test_schedule_invariants(p, min_bucket, ring):
 
 @pytest.mark.parametrize("p,min_bucket", itertools.product(PS, MIN_BUCKETS))
 def test_ring1_is_the_scan_plan(p, min_bucket):
-    """R=1 must reproduce the scan driver's historical plan exactly — the
-    host bucketing law m(r) = clamp(next_pow2(r), floor, next_pow2(p))."""
+    """R=1 must reproduce the scan driver's plan exactly — the law
+    m(r) = clamp(next_pow2(r), floor, top) with the top stage
+    top = min(next_pow2(p), round_up(p, floor))."""
     sched = make_schedule(p, min_bucket, ring=1)
-    cap = next_pow2(p)
     floor = next_pow2(max(min_bucket, 1))
+    cap = min(next_pow2(p), -(-p // floor) * floor)
     expect = [min(cap, max(floor, next_pow2(r))) for r in range(p, 1, -1)]
     got = [m for m, cnt, _ in sched.walk() for _ in range(cnt)]
     assert got == expect
+
+
+@pytest.mark.parametrize("p,ring,stages", [
+    # iJR904 (Table 1): 770 rows fill 800, not 1024
+    (770, 1, ((800, 258), (512, 256), (256, 128), (128, 64), (64, 32),
+              (32, 31))),
+    # e_coli_core (Table 1): 85 rows fill 96, not 128
+    (85, 1, ((96, 21), (64, 32), (32, 31))),
+    # Fig. 4's p=1000 ring: 1024 under both laws, the plan is unchanged
+    (1000, 4, ((1024, 488), (512, 256), (256, 128), (128, 64), (64, 32),
+               (32, 31))),
+])
+def test_top_stage_sized_to_the_data(p, ring, stages):
+    """The paper's shapes under the default floor of 32."""
+    assert make_schedule(p, 32, ring=ring).stages == stages
+
+
+@pytest.mark.parametrize("p,min_bucket,ring,pods,chunk,top", [
+    # the scan's threshold fits at the default floor and at the benches' 8
+    (770, 32, 1, 1, 16, 800),
+    (85, 32, 1, 1, 16, 96),
+    (770, 8, 1, 1, 16, 784),
+    (770, 16, 1, 1, 16, 784),
+    # the four-chip ring: 200-row blocks would cut the chunk to 10
+    (770, 32, 4, 1, 16, 832),
+    (1000, 32, 4, 1, 16, 1024),
+    (1000, 8, 4, 1, 16, 1024),
+    # the hierarchical (2, 4) ring of bench_table2, chunk 32
+    (770, 32, 4, 2, 32, 1024),
+])
+def test_top_stage_keeps_the_chunk(p, min_bucket, ring, pods, chunk, top):
+    """The shipped threshold configurations keep their full chunk in the
+    top stage: every shard's block there divides into whole chunks."""
+    sched = make_schedule(p, min_bucket, ring=ring, pods=pods, chunk=chunk)
+    assert sched.stages[0][0] == top
+    assert whole_chunk(chunk, sched.block(top)) == chunk
+
+
+@pytest.mark.parametrize("p,ring", itertools.product(PS + (770,), RINGS))
+@pytest.mark.parametrize("chunk", (16, 10))
+def test_chunk_never_below_the_pow2_plan(p, ring, chunk):
+    """No stage of a threshold plan gives a shard a smaller chunk than the
+    historical next_pow2 plan did; the dense plan (chunk 1) is never
+    larger than the threshold plan."""
+    min_bucket = 8
+    sched = make_schedule(p, min_bucket, ring=ring, chunk=chunk)
+    dense = make_schedule(p, min_bucket, ring=ring)
+    assert dense.stages[0][0] <= sched.stages[0][0]
+    floor = next_pow2(max(min_bucket, ring))
+    old = [ring] * (p - 1) if ring > next_pow2(p) else [
+        min(next_pow2(p), max(floor, next_pow2(r))) for r in range(p, 1, -1)]
+    new = [m for m, cnt, _ in sched.walk() for _ in range(cnt)]
+    assert len(new) == len(old) == p - 1
+    for m_new, m_old in zip(new, old):
+        assert m_new <= m_old
+        assert (whole_chunk(chunk, m_new // ring)
+                >= whole_chunk(chunk, m_old // ring)), (m_new, m_old)
+
+
+@pytest.mark.parametrize("p,min_bucket,want", [
+    (770, 32, (173_401_104, 120_723_696, 0.56, 0.37)),
+    (85, 32, (250_576, 175_648, 0.59, 0.42)),
+])
+def test_pair_slots_and_padded_share(p, min_bucket, want):
+    """``pair_slots`` under the historical next_pow2 top stage and under
+    the sized one, and the padded share ``1 - C(p+1, 3) / pair_slots`` of
+    a fit (iJR904: 0.56 -> 0.37)."""
+    floor = next_pow2(max(min_bucket, 1))
+    old = [min(next_pow2(p), max(floor, next_pow2(r)))
+           for r in range(p, 1, -1)]
+    old_slots = sum(m * (m - 1) // 2 for m in old)
+    new_slots = make_schedule(p, min_bucket).pair_slots
+    live = math.comb(p + 1, 3)
+    assert live == sum(r * (r - 1) // 2 for r in range(2, p + 1))
+    assert (old_slots, new_slots) == want[:2]
+    assert round(1 - live / old_slots, 2) == want[2]
+    assert round(1 - live / new_slots, 2) == want[3]
 
 
 @pytest.mark.parametrize("p", PS)
@@ -132,16 +229,15 @@ def test_invariant_violations_rejected_at_construction():
     "p,pods,ring", itertools.product(PS, (2, 4), (1, 2, 4))
 )
 def test_schedule_pod_invariants(p, pods, ring):
-    """With pods > 1 every stage size stays pow-2 AND a multiple of the
-    total shard count P*R (every shard of both levels keeps an equal
+    """With pods > 1 every stage size follows the stage-size law AND is a
+    multiple of the total shard count P*R (every shard of both levels keeps an equal
     non-empty block)."""
     sched = make_schedule(p, 4, ring=ring, pods=pods)
     shards = pods * ring
     assert sched.shards == shards
     assert sched.total_iterations == p - 1
+    _assert_stage_sizes(sched, p, 4, shards)
     for m, _cnt, _pos in sched.walk():
-        assert m & (m - 1) == 0
-        assert m % shards == 0
         assert sched.block(m) * shards == m
         assert sched.block(m) >= 1
 
